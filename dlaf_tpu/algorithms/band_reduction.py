@@ -342,11 +342,12 @@ def sbr_back_transform(tr: SbrTransforms, mat_e, out_cols: bool = False):
 
             def build_apply(CH=CH):
                 loop = partial(_bt_chunk_loop, b1=b1, b2=b2, CH=CH)
-                sm = coll.shard_map_compat(
+                sm = jax.shard_map(
                     lambda e, qc, sb: loop(e, qc, sb),
                     mesh=mesh,
                     in_specs=(colspec, P(), P()),
                     out_specs=colspec,
+                    check_vma=False,
                 )
                 return jax.jit(sm, out_shardings=col_sh, donate_argnums=(0,))
 

@@ -211,9 +211,9 @@ def band_to_tridiagonal_hh(mat_band: DistributedMatrix, band: int | None = None)
 def resolve_chase_backend() -> str:
     """Where the bulge chase runs (tune ``band_chase_backend``): 'auto'
     picks the batched-wavefront DEVICE kernel on accelerator backends —
-    removing the serial host ceiling (VERDICT r2 weak #2) — and the
-    threaded native host kernel on CPU (where the "device" kernel would
-    share cores with the host path)."""
+    removing the serial host ceiling — and the threaded native host kernel
+    on CPU (where the "device" kernel would share cores with the host
+    path)."""
     from dlaf_tpu.tune import get_tune_parameters
 
     be = get_tune_parameters().band_chase_backend

@@ -221,11 +221,12 @@ def bt_band_to_tridiagonal_hh_dist(
         def loop(va, ta, of, e_loc):
             return _wy_group_loop(e_loc, va, ta, of, w, g, G, kloc)
 
-        sm = coll.shard_map_compat(
+        sm = jax.shard_map(
             loop,
             mesh=mesh,
             in_specs=(P(), P(), P(), colspec),
             out_specs=colspec,
+            check_vma=False,
         )
 
         def run(x, va, ta, of, phj):

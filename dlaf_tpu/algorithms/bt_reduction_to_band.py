@@ -130,11 +130,12 @@ def _bt_r2b_cols(cols, mat_band: DistributedMatrix, taus: jax.Array):
         def kern(a, t, e):
             return _bt_r2b_cols_kernel(a, t, e, g_a=g_a, n_panels=n_panels, band=band)
 
-        sm = coll.shard_map_compat(
+        sm = jax.shard_map(
             kern,
             mesh=mesh,
             in_specs=(P(ROW_AXIS, COL_AXIS), P(), colspec),
             out_specs=colspec,
+            check_vma=False,
         )
 
         def run(a, t, gp):

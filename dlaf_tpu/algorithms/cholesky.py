@@ -50,15 +50,13 @@ from dlaf_tpu.plan import core as _plan
 
 
 def _diag_potrf(d):
-    """Diagonal-tile Cholesky: Pallas VMEM kernel for real dtypes (~5x the
-    XLA blocked path in-graph on TPU), XLA fallback otherwise."""
-    try:
-        from dlaf_tpu.ops import pallas_potrf
+    """Diagonal-tile Cholesky: the Pallas VMEM kernel for the tiles it
+    supports on TPU, XLA's blocked Cholesky otherwise (the ``supported``
+    gate decides; a kernel failure raises)."""
+    from dlaf_tpu.ops import pallas_potrf
 
-        if pallas_potrf.supported(d) and jax.default_backend() == "tpu":
-            return pallas_potrf.potrf_tile(d)
-    except Exception:
-        pass
+    if pallas_potrf.supported(d) and jax.default_backend() == "tpu":
+        return pallas_potrf.potrf_tile(d)
     return t.potrf(d, lower=True)
 
 
@@ -523,16 +521,17 @@ def _compiled_range(grid, g: _spmd.Geometry):
     """Compiled checkpoint-segment executable for the masked L kernel:
     ``(x, info, k0, k1) -> (x, info)`` with traced panel bounds, so the
     one executable serves every segment and every resumed continuation.
-    Built directly on ``shard_map_compat`` (not :func:`coll.spmd`, whose
+    Built directly on ``jax.shard_map`` (not :func:`coll.spmd`, whose
     uniform ``P('r','c')`` in_specs would shard the scalar bounds)."""
     def build():
         P = jax.sharding.PartitionSpec
         spec = P(ROW_AXIS, COL_AXIS)
-        sm = coll.shard_map_compat(
+        sm = jax.shard_map(
             partial(_chol_L_range_kernel, g=g),
             mesh=grid.mesh,
             in_specs=(spec, P(), P(), P()),
             out_specs=(spec, P()),
+            check_vma=False,
         )
         return jax.jit(sm, donate_argnums=(0,))
 

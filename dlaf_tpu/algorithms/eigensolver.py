@@ -194,9 +194,10 @@ def _band_stage_hh(band_mat: DistributedMatrix, band: int, want_q: bool = True):
     if m == 0:
         return None, None
     b2 = _sbr_target(band)
-    # a chase backend exists if the native lib built OR the device
-    # wavefront kernel is selected (the latter needs no toolchain)
-    chase_ok = get_lib() is not None or resolve_chase_backend() == "device"
+    # a chase backend exists if the device wavefront kernel is selected
+    # (it needs no toolchain, so the native lib is then not built) or the
+    # native lib built
+    chase_ok = resolve_chase_backend() == "device" or get_lib() is not None
     if b2 and chase_ok:
         from dlaf_tpu.algorithms.band_reduction import sbr_reduce
         from dlaf_tpu.common import stagetimer as st

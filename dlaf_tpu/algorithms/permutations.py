@@ -111,11 +111,12 @@ def _ring_fn(grid, dist, coord):
     def build():
         kern = _permute_rows_kernel if coord == "rows" else _permute_cols_kernel
         stacked = P(ROW_AXIS, COL_AXIS)
-        sm = coll.shard_map_compat(
+        sm = jax.shard_map(
             partial(kern, g=g),
             mesh=grid.mesh,
             in_specs=(stacked, P()),
             out_specs=stacked,
+            check_vma=False,
         )
         return jax.jit(sm)
 

@@ -287,17 +287,18 @@ def _red2band_range_kernel(x, taus_all, p0, p1, g: _spmd.Geometry, band: int):
 def _compiled_range(grid, g: _spmd.Geometry, band: int, prec: str):
     """Compiled checkpoint-segment executable:
     ``(x, taus_all, p0, p1) -> (x, taus_all)`` with traced panel bounds and
-    a replicated taus carry.  Built on ``shard_map_compat`` directly — the
+    a replicated taus carry.  Built on ``jax.shard_map`` directly — the
     scalar bounds and the replicated taus stack need ``P()`` in_specs that
     :func:`coll.spmd`'s uniform stacked specs cannot express."""
     def build():
         P = jax.sharding.PartitionSpec
         spec = P(ROW_AXIS, COL_AXIS)
-        sm = coll.shard_map_compat(
+        sm = jax.shard_map(
             partial(_red2band_range_kernel, g=g, band=band),
             mesh=grid.mesh,
             in_specs=(spec, P(), P(), P()),
             out_specs=(spec, P()),
+            check_vma=False,
         )
         return jax.jit(sm, donate_argnums=(0,))
 

@@ -44,10 +44,11 @@ restricted to the merging sub-block (the reference's sub-range
 pass additionally restricts rows to the pre-merge half-blocks where Q is
 supported, so the level cost is ~4 n s^2 / P flops instead of dense n^3.
 
-Leaves are dense ``eigh`` of tile-aligned diagonal blocks, sharded over the
-flat device mesh.  All subproblem sizes are powers of two times the leaf
-(padding poles are decoupled, larger than any true eigenvalue, and deflate
-to identity columns automatically), so every level is one static shape.
+Leaves are the tile-aligned diagonal blocks, solved on the host by LAPACK
+(``stemr`` in f64) as the reference does (tridiag_solver ``solveLeaf``),
+and placed sharded over the flat device mesh.  All subproblem sizes are
+powers of two times the leaf (padding poles are decoupled, larger than any
+true eigenvalue, and deflate to identity columns automatically), so every level is one static shape.
 """
 from __future__ import annotations
 
@@ -58,20 +59,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dlaf_tpu.comm import collectives as coll
 from dlaf_tpu.comm.grid import COL_AXIS, ROW_AXIS, Grid
 from dlaf_tpu.matrix.distribution import Distribution
-from dlaf_tpu.matrix.matrix import DistributedMatrix
+from dlaf_tpu.matrix.matrix import DistributedMatrix, place
 from dlaf_tpu.obs.trace import scope as _scope
 
 _BOTH = (ROW_AXIS, COL_AXIS)
 
 
 def _spmd(grid, fn, in_specs, out_specs, donate=()):
-    sm = coll.shard_map_compat(
-        fn, mesh=grid.mesh, in_specs=in_specs, out_specs=out_specs
+    sm = jax.shard_map(
+        fn, mesh=grid.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     return jax.jit(sm, donate_argnums=donate)
 
@@ -87,38 +88,33 @@ def _plan(n: int, nb: int, leaf_target: int):
 
 
 # --------------------------------------------------------------------------
-# leaf stage: sharded batched eigh of the tile-aligned diagonal blocks
+# leaf stage: host LAPACK eigensolves of the tile-aligned diagonal blocks,
+# placed into the sharded eigenvector matrix
 # --------------------------------------------------------------------------
 
 
-def _leaf_kernel(d_mod, e_pad, *, g, s0, nleaf, nloc, dt):
+def _leaf_eigh(d_mod, e_pad, s0: int, nleaf: int, nslots: int, rdt):
+    """Leaf eigenpairs on the host: eigenvalues [n_pad] and eigenvector
+    blocks [nslots, s0, s0] (slots past ``nleaf`` stay zero).  XLA's TPU
+    ``eigh`` missed 512x512 leaf eigenvalues by up to 3.4e-3 ||T|| in f32
+    (PR 21 chip run); LAPACK in f64 is exact to f32 rounding."""
+    import scipy.linalg as sla
+
+    lam = np.empty(nleaf * s0, rdt)
+    q = np.zeros((nslots, s0, s0), rdt)
+    for b in range(nleaf):
+        sl = slice(b * s0, (b + 1) * s0)
+        w, v = sla.eigh_tridiagonal(
+            d_mod[sl].astype(np.float64), e_pad[sl][:-1].astype(np.float64)
+        )
+        lam[sl], q[b] = w, v
+    return lam, q
+
+
+def _leaf_kernel(qL, *, g, s0, nleaf, nloc, dt):
+    """Place this device's leaf eigenvector blocks ``qL`` [nloc, s0, s0]
+    (leaves ``flat * nloc + i``) into the stacked eigenvector matrix."""
     myr, myc = coll.my_rank()
-    flat = myr * g.pc + myc
-    lb = jnp.arange(nloc)
-    b = flat * nloc + lb
-    bs = jnp.clip(b, 0, nleaf - 1)
-    valid = b < nleaf
-
-    def block(start):
-        dL = lax.dynamic_slice(d_mod, (start,), (s0,))
-        eL = lax.dynamic_slice(e_pad, (start,), (s0,))[: s0 - 1]
-        tri = dL[:, None] * jnp.eye(s0, dtype=dt)
-        ii = jnp.arange(s0 - 1)
-        tri = tri.at[ii + 1, ii].set(eL)
-        tri = tri.at[ii, ii + 1].set(eL)
-        return tri
-
-    with _scope("dc.leaf_eigh"):
-        tris = jax.vmap(block)(bs * s0)  # [nloc, s0, s0]
-        lamL, qL = jnp.linalg.eigh(tris)
-
-    # eigenvalues -> replicated [n_pad]
-    def put(i, buf):
-        pos = bs[i] * s0
-        cur = lax.dynamic_slice(buf, (pos,), (s0,))
-        return lax.dynamic_update_slice(buf, jnp.where(valid[i], lamL[i], cur), (pos,))
-
-    lam = lax.psum(lax.fori_loop(0, nloc, put, jnp.zeros_like(d_mod)), _BOTH)
 
     # eigenvectors -> stacked block-cyclic tiles: ONE all_gather round per
     # local leaf slot (nloc = nleaf/P rounds total, not nleaf sequential
@@ -129,7 +125,7 @@ def _leaf_kernel(d_mod, e_pad, *, g, s0, nleaf, nloc, dt):
     gi = jnp.arange(g.ltr) * g.pr + myr
     gj = jnp.arange(g.ltc) * g.pc + myc
 
-    def place(b2, qb, x):
+    def place_leaf(b2, qb, x):
         qt = qb.reshape(t0t, g.nb, t0t, g.nb).transpose(0, 2, 1, 3)
         ri = gi - b2 * t0t
         cj = gj - b2 * t0t
@@ -144,12 +140,12 @@ def _leaf_kernel(d_mod, e_pad, *, g, s0, nleaf, nloc, dt):
         qg = lax.all_gather(qsel, _BOTH)  # [P, s0, s0]
 
         def inner(q, x):
-            return place(q * nloc + lb2, qg[q], x)
+            return place_leaf(q * nloc + lb2, qg[q], x)
 
         return lax.fori_loop(0, P_, inner, x)
 
     x = lax.fori_loop(0, nloc, putq_round, jnp.zeros((g.ltr, g.ltc, g.nb, g.nb), dt))
-    return coll.relocal(x), lam
+    return coll.relocal(x)
 
 
 # --------------------------------------------------------------------------
@@ -635,20 +631,20 @@ def tridiag_dc_distributed(
     )
     from dlaf_tpu.plan import core as _plancache
 
+    nloc = -(-nleaf // Ptot)
+
     def build_leaf():
-        nloc = -(-nleaf // Ptot)
         return _spmd(
             grid,
             partial(_leaf_kernel, g=g, s0=s0, nleaf=nleaf, nloc=nloc, dt=dt),
-            in_specs=(rep, rep),
-            out_specs=(stacked, rep),
+            in_specs=(P(_BOTH),),
+            out_specs=stacked,
         )
 
     leaf_fn = _plancache.cached("dc_leaf", key0, build_leaf)
-    dm_dev = jnp.asarray(d_mod)
-    ep_dev = jnp.asarray(e_pad)
-    with matmul_precision(prec):
-        x, lam = leaf_fn(dm_dev, ep_dev)
+    lam0, q0 = _leaf_eigh(d_mod, e_pad, s0, nleaf, nloc * Ptot, rdt)
+    x = leaf_fn(place(q0, NamedSharding(grid.mesh, P(_BOTH))))
+    lam = jnp.asarray(lam0)
 
     for lvl in range(L):
         S = (s0 << lvl) * 2

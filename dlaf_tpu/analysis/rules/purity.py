@@ -41,7 +41,6 @@ TRACE_INTRODUCERS = {
     "remat": (0,),
     "pallas_call": (0,),
     "shard_map": (0,),
-    "shard_map_compat": (0,),
     "spmd": (1,),          # coll.spmd(grid, fn, ...)
     "fori_loop": (2,),     # lax.fori_loop(lo, hi, body, init)
     "scan": (0,),

@@ -417,23 +417,12 @@ def spmd(grid, fn, static_argnums=(), donate_argnums=(), out_specs=None):
     """
     P = jax.sharding.PartitionSpec
     spec = P(ROW_AXIS, COL_AXIS)
-    sm = shard_map_compat(
+    sm = jax.shard_map(
         fn, mesh=grid.mesh, in_specs=spec,
         out_specs=spec if out_specs is None else out_specs,
+        check_vma=False,
     )
     return jax.jit(sm, static_argnums=static_argnums, donate_argnums=donate_argnums)
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication check off, across jax versions:
-    ``jax.shard_map(check_vma=...)`` on >= 0.6, the experimental module with
-    ``check_rep=...`` before that."""
-    smap = getattr(jax, "shard_map", None)
-    if smap is not None:
-        return smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as smap
-
-    return smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
 
 
 def local(x):

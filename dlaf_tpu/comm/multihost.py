@@ -60,8 +60,7 @@ def initialize(
     wait for.
 
     ``initialization_timeout`` bounds the coordinator HANDSHAKE itself (in
-    seconds, passed through to ``jax.distributed.initialize`` on jax
-    versions that support it) — without it only the inter-attempt backoff
+    seconds, passed through to ``jax.distributed.initialize``) — without it only the inter-attempt backoff
     honors ``deadline_s`` while each individual handshake blocks for jax's
     default (5 minutes).  When unset but ``deadline_s`` is given, the
     remaining deadline budget is used, so the whole bring-up — handshakes
@@ -79,34 +78,20 @@ def initialize(
 
     import jax
 
-    # On CPU backends, cross-process computations need a host collectives
-    # implementation wired into the CPU client (jax >= 0.4.34 defaults to
-    # 'none' and compiles of multi-process programs fail with
-    # "Multiprocess computations aren't implemented on the CPU backend").
-    # Must be set BEFORE the backend comes up; harmless on TPU (the config
-    # only affects CPU client creation).
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # older jax: gloo is implicit
-        pass
-
-    import inspect
-
-    timeout_supported = (
-        "initialization_timeout"
-        in inspect.signature(jax.distributed.initialize).parameters
-    )
+    # CPU multi-process worlds need a host collectives implementation in
+    # the CPU client; the installed JAX defaults
+    # jax_cpu_collectives_implementation to 'gloo', so nothing is set here
+    # (TPU worlds use ICI/DCN and never read it).
     start = time.monotonic()
     attempt = 0
     while True:
         init_kwargs = {}
-        if timeout_supported:
-            timeout = initialization_timeout
-            if timeout is None and deadline_s is not None:
-                # bound each handshake by what is left of the deadline
-                timeout = max(deadline_s - (time.monotonic() - start), 1.0)
-            if timeout is not None:
-                init_kwargs["initialization_timeout"] = int(max(timeout, 1.0))
+        timeout = initialization_timeout
+        if timeout is None and deadline_s is not None:
+            # bound each handshake by what is left of the deadline
+            timeout = max(deadline_s - (time.monotonic() - start), 1.0)
+        if timeout is not None:
+            init_kwargs["initialization_timeout"] = int(max(timeout, 1.0))
         try:
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,

@@ -18,17 +18,17 @@ import jax.numpy as jnp
 import numpy as np
 
 import dlaf_tpu.testing as tu
-from dlaf_tpu.miniapp.common import DTYPES, sync
+from dlaf_tpu.miniapp.common import DTYPES
 from dlaf_tpu.ops import tile as t
 
 
 def _time(fn, *args, nreps: int) -> float:
     r = fn(*args)
-    sync(r[0] if isinstance(r, tuple) else r)
+    jax.block_until_ready(r)
     t0 = time.perf_counter()
     for _ in range(nreps):
         r = fn(*args)
-    sync(r[0] if isinstance(r, tuple) else r)
+    jax.block_until_ready(r)
     return (time.perf_counter() - t0) / nreps
 
 

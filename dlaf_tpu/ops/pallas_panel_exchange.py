@@ -162,6 +162,23 @@ def collective_id_for(kind: str, axis: str) -> int:
 # (slots, ...) payload (or a scalar-have whole-payload broadcast) onto it.
 
 
+#: lanes of every in-kernel have-mask: Mosaic slices and DMAs only
+#: lane-aligned VMEM buffers, so a (slots, 1) mask travels as
+#: (slots, HAVE_LANES) with the flag repeated in every lane
+HAVE_LANES = 128
+
+
+def _lanes(h):
+    """(slots, 1) have-mask -> the lane-dense (slots, HAVE_LANES) kernel form."""
+    return jnp.broadcast_to(h, (h.shape[0], HAVE_LANES))
+
+
+def _flag(take, like):
+    """Lane 0 of a lane-dense (slots, HAVE_LANES) mask, shaped to broadcast
+    against ``like`` (leading axis = slots, or a single slot)."""
+    return take[:, :1].reshape((take.shape[0],) + (1,) * (like.ndim - 1))
+
+
 def _to_wire(y, have):
     slots = int(np.prod(have.shape)) if have.ndim else 1
     yf = y.reshape(slots, -1)
@@ -228,12 +245,13 @@ def _ppermute_ring(yf, h, axis: str, n: int, interpret: bool):
 def _neighbor_ids(ring_axis: str, mesh_axes: tuple, offset: int):
     """device_id (and its type) of the rank ``offset`` steps along the ring.
 
-    Single-axis meshes address by scalar logical index (also the only form
-    the 0.4.37 interpreter discharges); multi-axis meshes address by the
-    full mesh coordinate tuple with the ring axis advanced."""
+    Single-axis meshes address by scalar logical index (the only form the
+    interpreter discharges); multi-axis meshes address by the full mesh
+    coordinate tuple with the ring axis advanced."""
     n = _axis_size(ring_axis)
     me = lax.axis_index(ring_axis)
-    step = (me + offset + n) % n  # weak-typed literals keep the index i32
+    # explicit int32 operands: Mosaic lowers no int64 (jax_enable_x64)
+    step = lax.rem(me + np.int32(offset + n), np.int32(n))
     if len(mesh_axes) == 1:
         return step, pltpu.DeviceIdType.LOGICAL
     coords = tuple(
@@ -272,7 +290,7 @@ def _ring_hops(
     ``backpressure=False`` is for the interpreter only (ranks execute
     sequentially; remote semaphore signals are not discharged there)."""
     for s in range(nhops):  # static: P-1 hops
-        slot = s % 2
+        slot = np.int32(s % 2)  # int32 index: Mosaic lowers no int64
         if backpressure and s >= 2:
             # downstream neighbor must have merged our hop s-2 copy out of
             # this landing slot before we overwrite it with hop s
@@ -302,7 +320,7 @@ def _ring_hops(
         have = acc_h[...]
         h_in = land_h[slot]
         take = jnp.logical_and(have == 0, h_in != 0)
-        acc_y[...] = jnp.where(take, land_y[slot], acc_y[...])
+        acc_y[...] = jnp.where(_flag(take, acc_y), land_y[slot], acc_y[...])
         acc_h[...] = have | h_in
         if backpressure and s + 2 < nhops:
             # slot consumed: the upstream writer may reuse it at hop s+2
@@ -361,9 +379,10 @@ def dma_ring_exchange(yf, h, ring_axis: str, mesh_axes: tuple,
     n = _axis_size(ring_axis)
     if n == 1:
         return yf, h
+    hl = _lanes(h)
     scratch = [
         pltpu.VMEM((2,) + yf.shape, yf.dtype),
-        pltpu.VMEM((2,) + h.shape, h.dtype),
+        pltpu.VMEM((2,) + hl.shape, h.dtype),
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
@@ -377,18 +396,19 @@ def dma_ring_exchange(yf, h, ring_axis: str, mesh_axes: tuple,
         mesh_axes=mesh_axes,
         sync=not interpret,
     )
-    return pl.pallas_call(
+    oy, oh = pl.pallas_call(
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct(yf.shape, yf.dtype),
-            jax.ShapeDtypeStruct(h.shape, h.dtype),
+            jax.ShapeDtypeStruct(hl.shape, h.dtype),
         ),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             collective_id=collective_id, has_side_effects=True
         ),
-    )(yf, h)
+    )(yf, hl)
+    return oy, oh[:, :1]
 
 
 # ------------------------------------------------------------- entry points
@@ -434,11 +454,11 @@ def ring_bcast(x, is_root, axis: str, *, mesh_axes=("r", "c")):
 
 def fusion_supported(d, xc) -> bool:
     """The fused factor-and-send kernel covers the lookahead Cholesky panel
-    case: real f32/f64 tiles, MXU/VPU-aligned tile side (the composed trsm
+    case: f32 tiles, MXU/VPU-aligned tile side (the composed trsm
     kernel column-blocks by 32 and Mosaic wants lane-width multiples), and
     a panel that is a stack of square tiles."""
     return (
-        np.dtype(d.dtype).kind == "f"
+        np.dtype(d.dtype) == np.dtype(np.float32)  # Mosaic has no f64
         and d.ndim == 2
         and d.shape[0] == d.shape[1]
         and xc.ndim == 3
@@ -446,6 +466,14 @@ def fusion_supported(d, xc) -> bool:
         and d.shape[0] % 128 == 0
         and d.shape[0] <= _ptrsm.MAX_NB
     )
+
+
+def _row_blocks(mask, mb: int):
+    """(ltr, HAVE_LANES) per-tile mask -> (ltr*mb, mb) per-element mask
+    (Mosaic has no 1-D gather; a broadcast + reshape expands it)."""
+    ltr = mask.shape[0]
+    m = jnp.broadcast_to(mask[:, :1].reshape(ltr, 1, 1), (ltr, mb, mb))
+    return m.reshape(ltr * mb, mb)
 
 
 def _fused_kernel(d_ref, xc_ref, root_ref, below_ref, lkk_ref, cp_ref,
@@ -473,9 +501,7 @@ def _fused_kernel(d_ref, xc_ref, root_ref, below_ref, lkk_ref, cp_ref,
     me = lax.axis_index(ring_axis)
     root = root_ref[0, 0]
     is_root = (me == root).astype(jnp.int32)
-    below = below_ref[...]  # (ltr, 1) int32: gi > k
-    rows = lax.broadcasted_iota(jnp.int32, cp_ref.shape, 0) // mb
-    keep = jnp.take(below[:, 0], rows) * is_root
+    keep = _row_blocks(below_ref[...], mb) * is_root  # below: gi > k
     cp_ref[...] = jnp.where(keep != 0, cp_ref[...], jnp.zeros_like(cp_ref))
     acc_h[...] = jnp.full(acc_h.shape, is_root)
 
@@ -511,12 +537,12 @@ def fused_factor_bcast(d, xc, below, root, ring_axis: str = "c",
     herm = jnp.tril(d) + jnp.tril(d, -1).T
     flat = xc.reshape(ltr * mb, mb)
     root_arr = jnp.asarray(root, jnp.int32).reshape(1, 1)
-    below_arr = below.astype(jnp.int32).reshape(ltr, 1)
+    below_arr = _lanes(below.astype(jnp.int32).reshape(ltr, 1))
     scratch = [
         pltpu.VMEM((mb, mb), d.dtype),                 # u = tril(L)^T
         pltpu.VMEM((2, ltr * mb, mb), d.dtype),        # landing slots
-        pltpu.VMEM((2, 1, 1), jnp.int32),
-        pltpu.VMEM((1, 1), jnp.int32),                 # have accumulator
+        pltpu.VMEM((2, 1, HAVE_LANES), jnp.int32),
+        pltpu.VMEM((1, HAVE_LANES), jnp.int32),        # have accumulator
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
@@ -537,7 +563,7 @@ def fused_factor_bcast(d, xc, below, root, ring_axis: str = "c",
             jax.ShapeDtypeStruct((ltr * mb, mb), d.dtype),
         ),
         scratch_shapes=scratch,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=FUSED_COLLECTIVE_ID, has_side_effects=True
         ),
     )(herm, flat, root_arr, below_arr)

@@ -31,32 +31,34 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
+# int32 block index: under jax_enable_x64 a Python 0 is int64, which
+# Mosaic cannot return from an index map
+_I0 = np.int32(0)
 W = 32  # sub-triangle sweep width (one MXU tile side)
 
 
 def _kernel(l_ref, b_ref, o_ref, *, nb: int):
-    ell = l_ref[...]  # (nb, nb) lower factor, already op()-resolved to L^T form
-    b = b_ref[...]  # (bm, nb)
-    bm = b.shape[0]
-    nblk = nb // W
+    # l_ref: (nb, nb) factor, already op()-resolved to U = L^T form;
+    # b_ref/o_ref: (bm, nb).  Column blocks are read and written through
+    # ref slices (``pl.ds``): Mosaic lowers no value-level dynamic_slice.
+    bm = b_ref.shape[0]
+    dtype = o_ref.dtype
     r2 = lax.broadcasted_iota(jnp.int32, (bm, W), 1)  # column index within block
     cw = lax.broadcasted_iota(jnp.int32, (W, W), 1)
     rw = lax.broadcasted_iota(jnp.int32, (W, W), 0)
-    x = jnp.zeros_like(b)
-    for j in range(nblk):  # static: nb/W blocks
+    for j in range(nb // W):  # static: nb/W blocks
         c0 = j * W
-        bj = lax.dynamic_slice(b, (0, c0), (bm, W))
+        bj = b_ref[:, pl.ds(c0, W)]
         if j:
-            # MXU update: B_j -= X_{<j} @ L^T[<j, j]  (we keep X full-width,
-            # zero beyond solved columns, so the full GEMM is equivalent)
-            ltj = lax.dynamic_slice(ell, (0, c0), (nb, W))  # rows <j matter
+            # MXU update: B_j -= X_{<j} @ U[<j, j]
             bj = bj - jax.lax.dot_general(
-                x, ltj, (((1,), (0,)), ((), ())),
-                preferred_element_type=b.dtype,  # keep f64 accumulation f64
+                o_ref[:, pl.ds(0, c0)], l_ref[pl.ds(0, c0), pl.ds(c0, W)],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=dtype,  # keep f64 accumulation f64
             )
         # W-step masked triangular sweep against the diagonal block
         # (upper-triangular W x W: ljj[s, t] multiplies solved col s into t)
-        ljj = lax.dynamic_slice(ell, (c0, c0), (W, W))
+        ljj = l_ref[pl.ds(c0, W), pl.ds(c0, W)]
 
         def step(t, xj):
             # contribution of solved columns s < t
@@ -67,9 +69,8 @@ def _kernel(l_ref, b_ref, o_ref, *, nb: int):
             newcol = (bcol - contrib) / dt_
             return jnp.where(r2 == t, newcol[:, None], xj)
 
-        xj = lax.fori_loop(0, W, step, jnp.zeros((bm, W), b.dtype))
-        x = lax.dynamic_update_slice(x, xj, (0, c0))
-    o_ref[...] = x
+        o_ref[:, pl.ds(c0, W)] = lax.fori_loop(
+            jnp.int32(0), jnp.int32(W), step, jnp.zeros((bm, W), dtype))
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -91,10 +92,10 @@ def panel_trsm_right_lower_t(ell, b, conj: bool = False, interpret: bool = False
         functools.partial(_kernel, nb=nb),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((nb, nb), lambda i: (0, 0)),
-            pl.BlockSpec((bm, nb), lambda i: (i, 0)),
+            pl.BlockSpec((nb, nb), lambda i: (_I0, _I0)),
+            pl.BlockSpec((bm, nb), lambda i: (i, _I0)),
         ],
-        out_specs=pl.BlockSpec((bm, nb), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, nb), lambda i: (i, _I0)),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         interpret=interpret,
     )(u, b)

@@ -6,7 +6,7 @@ Cholesky costs ~5 ms for a 256-tile on v5e (latency-bound recursion), while
 the whole tile fits in VMEM and an unblocked right-looking sweep is a
 ``fori_loop`` of vectorized rank-1 updates.
 
-Real dtypes only (complex falls back to the XLA path in ops/tile.py).
+f32 tiles only (other dtypes take the XLA path in ops/tile.py).
 """
 from __future__ import annotations
 
@@ -36,7 +36,9 @@ def _potrf_kernel(a_ref, o_ref):
         a = a - jnp.where(c2 > j, upd, 0.0)
         return a
 
-    o_ref[...] = lax.fori_loop(0, n, body, a)
+    # int32 bounds: under jax_enable_x64 a Python-int loop index is int64,
+    # which Mosaic cannot lower
+    o_ref[...] = lax.fori_loop(jnp.int32(0), jnp.int32(n), body, a)
 
 
 @partial(jax.jit, static_argnums=())
@@ -50,12 +52,19 @@ def potrf_tile(a):
     )(herm)
 
 
+# VMEM guard: the tile and its output are resident; 1024^2 f32 is 4 MiB each
+MAX_NB = 1024
+
+
 def supported(a) -> bool:
+    """f32 only: Mosaic has no f64 (the emulated-f64 and complex tiles take
+    XLA's blocked Cholesky)."""
     import numpy as np
 
     return (
-        np.dtype(a.dtype).kind == "f"
-        and a.ndim >= 2
+        np.dtype(a.dtype) == np.dtype(np.float32)
+        and a.ndim == 2
         and a.shape[-1] == a.shape[-2]
         and a.shape[-1] % 8 == 0
+        and a.shape[-1] <= MAX_NB
     )

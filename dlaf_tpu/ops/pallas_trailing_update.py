@@ -85,6 +85,11 @@ from dlaf_tpu.ops import tile as t
 #: that makes per-hop application bit-equal to the one-shot einsum
 TRAILING_SUBSCRIPTS = "iab,jcb->ijac"
 
+#: scoped-VMEM limit of the ring kernels that keep the whole local trailing
+#: matrix resident (of v5e's 128 MiB): the default 16 MiB scope does not
+#: hold even a 2x2-tile local matrix's in-kernel contraction
+VMEM_LIMIT_BYTES = 100 << 20
+
 
 def consume_schedule(nhops: int) -> list:
     """The per-hop event order of :func:`dma_ring_consume`, as data.
@@ -266,12 +271,12 @@ def _apply_update(ox_ref, cp_ref, y, mask, *, subscripts):
     """Subtract the masked panel contribution from the trailing accumulator.
 
     ``y[slots, mb, nb]`` is the operand source (a landing slot or the local
-    contribution), ``mask[slots, 1]`` selects the slots to apply; the rest
+    contribution), lane-dense ``mask[slots, HAVE_LANES]`` selects the slots
+    to apply; the rest
     contribute an exactly-zero operand — the same zero contribution the
     one-shot einsum carries for masked slots, so summing per-hop
     applications reproduces its per-element arithmetic."""
-    m = (mask != 0).reshape(mask.shape[0], 1, 1)
-    contrib = jnp.where(m, y, jnp.zeros_like(y))
+    contrib = jnp.where(ppe._flag(mask != 0, y), y, jnp.zeros_like(y))
     ox_ref[...] = ox_ref[...] - t.contract(subscripts, cp_ref[...], contrib)
 
 
@@ -285,7 +290,7 @@ def _consume_hops(
     order).  The update reads the fresh tiles straight out of landing slot
     ``s%2``; the ack after it is the slot-reuse backpressure."""
     for s in range(nhops):
-        slot = s % 2
+        slot = np.int32(s % 2)  # int32 index: Mosaic lowers no int64
         if backpressure and s >= 2:
             pltpu.semaphore_wait(cap_sem.at[slot], 1)
         cp_y = pltpu.make_async_remote_copy(
@@ -307,9 +312,7 @@ def _consume_hops(
         have = acc_h[...]
         h_in = land_h[slot]
         take = jnp.logical_and(have == 0, h_in != 0)
-        acc_y[...] = jnp.where(
-            take.reshape(take.shape[0], 1, 1), land_y[slot], acc_y[...]
-        )
+        acc_y[...] = jnp.where(ppe._flag(take, acc_y), land_y[slot], acc_y[...])
         acc_h[...] = have | h_in
         # consume hop s out of its landing slot while hop s+1 is in flight
         _apply_update(
@@ -387,9 +390,10 @@ def dma_ring_consume(x, yf, h, cp, z, ring_axis: str, mesh_axes: tuple,
         m = ((h != 0) & (z == 0)).reshape(h.shape[0], 1, 1)
         contrib = jnp.where(m, yf, jnp.zeros_like(yf))
         return x - t.contract(subscripts, cp, contrib), yf, h
+    hl, zl = ppe._lanes(h), ppe._lanes(z)
     scratch = [
         pltpu.VMEM((2,) + yf.shape, yf.dtype),
-        pltpu.VMEM((2,) + h.shape, h.dtype),
+        pltpu.VMEM((2,) + hl.shape, h.dtype),
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
@@ -404,19 +408,21 @@ def dma_ring_consume(x, yf, h, cp, z, ring_axis: str, mesh_axes: tuple,
         sync=not interpret,
         subscripts=subscripts,
     )
-    return pl.pallas_call(
+    x2, y2, h2 = pl.pallas_call(
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct(yf.shape, yf.dtype),
-            jax.ShapeDtypeStruct(h.shape, h.dtype),
+            jax.ShapeDtypeStruct(hl.shape, h.dtype),
         ),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            collective_id=collective_id, has_side_effects=True
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            collective_id=collective_id, has_side_effects=True,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
-    )(x, yf, h, cp, z)
+    )(x, yf, hl, cp, zl)
+    return x2, y2, h2[:, :1]
 
 
 # ----------------------------------------------------- fused orchestration
@@ -475,13 +481,13 @@ def fused_transpose_update(x, cp, taken, have, suppress, ring_axis: str, *,
 
 
 def fused_step_supported(x, cp) -> bool:
-    """The single-kernel lookahead step covers the real-dtype square-tile
+    """The single-kernel lookahead step covers the f32 square-tile
     Cholesky case with MXU/VPU-aligned tile side (same alignment gates as
     ``ppe.fusion_supported`` — the composed trsm kernel column-blocks by 32
     and Mosaic wants lane-width multiples)."""
     mb = x.shape[-1]
     return (
-        np.dtype(x.dtype).kind == "f"
+        np.dtype(x.dtype) == np.dtype(np.float32)  # Mosaic has no f64
         and x.ndim == 4
         and x.shape[-2] == mb
         and cp.ndim == 3
@@ -494,9 +500,14 @@ def fused_step_supported(x, cp) -> bool:
 def _masked_tile(stack, idx_ref_val, axis_len: int):
     """stack[idx] for a traced idx, as a masked sum (Mosaic-friendly: no
     dynamic gather) — requires the mask to select at most one slot."""
-    sel = (jnp.arange(axis_len) == idx_ref_val).astype(stack.dtype)
-    sel = sel.reshape((axis_len,) + (1,) * (stack.ndim - 1))
-    return jnp.sum(stack * sel, axis=0)
+    sel = (_iota((axis_len,) + (1,) * (stack.ndim - 1), 0) == idx_ref_val)
+    return jnp.sum(stack * sel.astype(stack.dtype), axis=0)
+
+
+def _iota(shape, axis: int):
+    """Index along ``axis`` at full rank (Mosaic cannot reshape a 1-D
+    vector into a higher-rank one)."""
+    return lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
 def _fused_step_kernel(
@@ -561,19 +572,19 @@ def _fused_step_kernel(
 
     # -- 2. narrow update: column k+1 only, from the merged row panel
     rp1 = _masked_tile(
-        jnp.where((orh_ref[...] != 0).reshape(ltc, 1, 1), orp_ref[...],
+        jnp.where(ppe._flag(orh_ref[...] != 0, orp_ref), orp_ref[...],
                   jnp.zeros_like(orp_ref[...])),
         l_next, ltc,
     )
     upd1 = t.contract("iab,cb->iac", cp_ref[...], rp1)
     colmask = (
-        (jnp.arange(ltc) == l_next) & (me_c == kc1)
-    ).astype(ox_ref.dtype).reshape(1, ltc, 1, 1)
+        (_iota((1, ltc, 1, 1), 1) == l_next) & (me_c == kc1)
+    ).astype(ox_ref.dtype)
     ox_ref[...] = ox_ref[...] - upd1[:, None] * colmask
 
     # -- 3. diagonal tile of step k+1 -> everyone ('c' ring then 'r' ring)
-    rsel = (jnp.arange(ltr) == lkr1).astype(ox_ref.dtype).reshape(ltr, 1, 1, 1)
-    csel = (jnp.arange(ltc) == lkc1).astype(ox_ref.dtype).reshape(1, ltc, 1, 1)
+    rsel = (_iota((ltr, 1, 1, 1), 0) == lkr1).astype(ox_ref.dtype)
+    csel = (_iota((1, ltc, 1, 1), 1) == lkc1).astype(ox_ref.dtype)
     d_own = jnp.sum(ox_ref[...] * rsel * csel, axis=(0, 1))
     own = (me_r == kr1) & (me_c == kc1)
     od_ref[...] = jnp.where(own, d_own, jnp.zeros_like(d_own))
@@ -591,14 +602,13 @@ def _fused_step_kernel(
     dh_ref[...] = jnp.tril(od_ref[...]) + jnp.tril(od_ref[...], -1).T
     _ppotrf._potrf_kernel(dh_ref, olkk_ref)
     u_ref[...] = jnp.tril(olkk_ref[...]).T
-    xsel = (jnp.arange(ltc) == l_next).astype(ox_ref.dtype).reshape(1, ltc, 1, 1)
+    xsel = (_iota((1, ltc, 1, 1), 1) == l_next).astype(ox_ref.dtype)
     xc_ref[...] = jnp.sum(ox_ref[...] * xsel, axis=1).reshape(ltr * mb, mb)
     _ptrsm._kernel(u_ref, xc_ref, ocp_ref, nb=mb)
 
     # -- 5. mask to sub-diagonal rows of the owning column, ring-send ('c')
     is_root = (me_c == kc1).astype(jnp.int32)
-    rows = lax.broadcasted_iota(jnp.int32, ocp_ref.shape, 0) // mb
-    keep = jnp.take(below_ref[...][:, 0], rows) * is_root
+    keep = ppe._row_blocks(below_ref[...], mb) * is_root
     ocp_ref[...] = jnp.where(keep != 0, ocp_ref[...], jnp.zeros_like(ocp_ref))
     acc_h[...] = jnp.full(acc_h.shape, is_root)
     ppe._ring_hops(
@@ -624,25 +634,26 @@ def fused_step(x, taken, have, suppress, cp, below1, params,
     mb = x.shape[-1]
     nr = ppe._axis_size("r")
     nc = ppe._axis_size("c")
-    h = have.astype(jnp.int32).reshape(ltc, 1)
-    z = suppress.astype(jnp.int32).reshape(ltc, 1)
-    below_arr = below1.astype(jnp.int32).reshape(ltr, 1)
+    h = ppe._lanes(have.astype(jnp.int32).reshape(ltc, 1))
+    z = ppe._lanes(suppress.astype(jnp.int32).reshape(ltc, 1))
+    below_arr = ppe._lanes(below1.astype(jnp.int32).reshape(ltr, 1))
     par = params.astype(jnp.int32).reshape(1, 8)
+    HL = ppe.HAVE_LANES
     dma2 = pltpu.SemaphoreType.DMA((2,))
     reg2 = pltpu.SemaphoreType.REGULAR((2,))
     scratch = [
         pltpu.VMEM((2, ltc, mb, mb), x.dtype),     # consume landing slots
-        pltpu.VMEM((2, ltc, 1), jnp.int32),
+        pltpu.VMEM((2, ltc, HL), jnp.int32),
         pltpu.VMEM((2, mb, mb), x.dtype),          # d 'c'-ring landing
-        pltpu.VMEM((2, 1, 1), jnp.int32),
+        pltpu.VMEM((2, 1, HL), jnp.int32),
         pltpu.VMEM((2, mb, mb), x.dtype),          # d 'r'-ring landing
-        pltpu.VMEM((2, 1, 1), jnp.int32),
+        pltpu.VMEM((2, 1, HL), jnp.int32),
         pltpu.VMEM((2, ltr * mb, mb), x.dtype),    # cp send landing
-        pltpu.VMEM((2, 1, 1), jnp.int32),
+        pltpu.VMEM((2, 1, HL), jnp.int32),
         pltpu.VMEM((mb, mb), x.dtype),             # u = tril(L)^T
         pltpu.VMEM((ltr * mb, mb), x.dtype),       # flattened panel column
         pltpu.VMEM((mb, mb), x.dtype),             # hermitized diag tile
-        pltpu.VMEM((1, 1), jnp.int32),             # have accumulator
+        pltpu.VMEM((1, HL), jnp.int32),            # have accumulator
     ] + [dma2, dma2, dma2, dma2, reg2] * 4         # one sem set per phase
     kernel = functools.partial(
         _fused_step_kernel,
@@ -653,17 +664,17 @@ def fused_step(x, taken, have, suppress, cp, below1, params,
         out_shape=(
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct((ltc, mb, mb), x.dtype),
-            jax.ShapeDtypeStruct((ltc, 1), jnp.int32),
+            jax.ShapeDtypeStruct((ltc, HL), jnp.int32),
             jax.ShapeDtypeStruct((mb, mb), x.dtype),
             jax.ShapeDtypeStruct((mb, mb), x.dtype),
             jax.ShapeDtypeStruct((ltr * mb, mb), x.dtype),
         ),
         scratch_shapes=scratch,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=ppe.collective_id_for("fused_step", "r"),
             has_side_effects=True,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(x, taken, h, z, cp, below_arr, par)
-    amask = (rh != 0).reshape(ltc, 1, 1)
-    rp = jnp.where(amask, rp, jnp.zeros_like(rp))
+    rp = jnp.where(ppe._flag(rh != 0, rp), rp, jnp.zeros_like(rp))
     return x2, rp, lkk1, cp1.reshape(ltr, mb, mb), d1

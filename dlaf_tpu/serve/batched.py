@@ -220,8 +220,8 @@ def _build_chol_exec(grid: Grid, dist: Distribution, shard_batch: bool, variant:
     mesh = _mesh3(grid, shard_batch)
     kern = partial(_CHOL_KERNELS[variant], g=g, want_info=True)
     spec = P(BATCH_AXIS, ROW_AXIS, COL_AXIS)
-    sm = coll.shard_map_compat(
-        jax.vmap(kern), mesh=mesh, in_specs=spec, out_specs=(spec, P(BATCH_AXIS))
+    sm = jax.shard_map(
+        jax.vmap(kern), mesh=mesh, in_specs=spec, out_specs=(spec, P(BATCH_AXIS)), check_vma=False
     )
     return jax.jit(sm, donate_argnums=(0,))
 
@@ -258,11 +258,12 @@ def _build_posv_batch_exec(grid: Grid, dist: Distribution, variant: str, uplo: s
             sols.append(sol)
         return jnp.stack(sols), info
 
-    sm = coll.shard_map_compat(
+    sm = jax.shard_map(
         solve_all,
         mesh=mesh,
         in_specs=(P(BATCH_AXIS, ROW_AXIS, COL_AXIS), P(BATCH_AXIS)),
         out_specs=(P(BATCH_AXIS), P(BATCH_AXIS)),
+        check_vma=False,
     )
     return jax.jit(sm, donate_argnums=(1,))
 
@@ -292,9 +293,10 @@ def _build_posv_matrix_exec(grid: Grid, dist_a: Distribution, dist_b: Distributi
         return sol, info
 
     spec = P(BATCH_AXIS, ROW_AXIS, COL_AXIS)
-    sm = coll.shard_map_compat(
+    sm = jax.shard_map(
         jax.vmap(one), mesh=mesh, in_specs=(spec, spec),
         out_specs=(spec, P(BATCH_AXIS)),
+        check_vma=False,
     )
     return jax.jit(sm, donate_argnums=(1,))
 
@@ -311,9 +313,10 @@ def _build_eig_exec(grid: Grid):
         bad = jnp.sum(~jnp.isfinite(w)) + jnp.sum(~jnp.isfinite(v.real))
         return w, v, bad.astype(jnp.int32)
 
-    sm = coll.shard_map_compat(
+    sm = jax.shard_map(
         jax.vmap(one), mesh=mesh, in_specs=P(BATCH_AXIS),
         out_specs=(P(BATCH_AXIS), P(BATCH_AXIS), P(BATCH_AXIS)),
+        check_vma=False,
     )
     return jax.jit(sm, donate_argnums=(0,))
 

@@ -44,6 +44,7 @@ import tempfile
 import threading
 import time
 
+from dlaf_tpu import tune
 from dlaf_tpu.health import DeviceUnresponsiveError, DistributionError
 from dlaf_tpu.obs import metrics as om
 from dlaf_tpu.obs import telemetry as tlm
@@ -89,8 +90,12 @@ class Fleet:
             raise DistributionError("fleet: need at least one worker")
         self.base_dir = base_dir or tempfile.mkdtemp(prefix="dlaf-fleet-")
         os.makedirs(self.base_dir, exist_ok=True)
-        cache_dir = os.environ.get("DLAF_TPU_COMPILE_CACHE") or os.path.join(
-            self.base_dir, "compile-cache"
+        # one compile cache shared by every worker: the operator's (workers
+        # inherit JAX_COMPILATION_CACHE_DIR, which wins), else the caller's
+        # base_dir, else the checkout's fixed default
+        cache_dir = os.environ.get("DLAF_TPU_COMPILE_CACHE") or (
+            os.path.join(base_dir, "compile-cache") if base_dir
+            else tune.DEFAULT_COMPILE_CACHE
         )
         env = {
             "DLAF_TPU_COMPILE_CACHE": cache_dir,

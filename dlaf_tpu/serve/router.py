@@ -2,9 +2,9 @@
 
 One :class:`~dlaf_tpu.serve.pool.SolverPool` serves one device mesh; a
 production deployment runs several (one per slice, or per host fallback
-mesh) and must keep serving when a mesh wedges — on real pods the
-dominant failure is a hung TPU tunnel, not a crashed process, so the
-pool's queue is still intact when the device stops answering.  The
+mesh) and must keep serving when a mesh wedges — a hung runtime, not a
+crashed process, so the pool's queue is still intact when the device
+stops answering.  The
 router's job is to notice (bounded
 :class:`~dlaf_tpu.resilience.DeviceWatchdog` probes), classify
 (:class:`~dlaf_tpu.health.DeviceUnresponsiveError`), and MIGRATE: drain

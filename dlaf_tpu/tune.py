@@ -123,9 +123,8 @@ class TuneParameters:
     - ``band_chase_backend``: where the small-band -> tridiagonal bulge
       chase runs: 'native' (threaded C++ host kernel), 'device' (batched
       wavefront on the accelerator, algorithms/band_chase_device.py), or
-      'auto' (device when the default JAX backend is an accelerator, else
-      native — on CPU the "device" kernel shares the cores with the host
-      path and loses).
+      'auto' (native whenever the C++ kernel builds, device otherwise —
+      the device wavefront is latency-bound on a v5e chip).
     - ``band_chase_device_block``: sweeps per device-chase block (bounds
       on-device reflector storage; each block stages its reflectors to
       host on completion).
@@ -622,83 +621,51 @@ def initialize(**overrides) -> TuneParameters:
 
 _compile_cache_dir: str | None = None
 
-
-def _host_fingerprint() -> str:
-    """Short hash of the host's CPU feature flags (ISA compatibility).
-    x86 cpuinfo says 'flags', aarch64 says 'Features'; if neither appears,
-    hash the whole cpuinfo rather than degrade to a constant."""
-    import hashlib
-
-    try:
-        with open("/proc/cpuinfo") as f:
-            txt = f.read()
-        for line in txt.splitlines():
-            if line.startswith(("flags", "Features")):
-                return hashlib.sha1(line.encode()).hexdigest()[:8]
-        return hashlib.sha1(txt.encode()).hexdigest()[:8]
-    except OSError:
-        import platform
-
-        return hashlib.sha1(
-            f"{platform.machine()}-{platform.processor()}".encode()
-        ).hexdigest()[:8]
+# The compile cache's home unless the environment names one: a fixed
+# directory in the checkout (a moving path never hits; .gitignore lists it)
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
 
-def setup_compile_cache(base: str | None = None, *, default_base: str | None = None,
+def setup_compile_cache(base: str | None = None, *,
                         min_compile_s: float | None = None,
                         force: bool = False) -> str | None:
     """Configure the JAX persistent compilation cache so repeated processes
     skip backend compiles (the zero-compile cold start — see
-    ``dlaf_tpu.plan``).  Resolution: explicit ``base`` argument, else env
-    ``DLAF_TPU_COMPILE_CACHE``, else ``default_base`` (the miniapp harness
-    passes ``~/.cache/dlaf_tpu_xla``; the library default is OFF so plain
-    ``tune.initialize()`` only enables the cache when the operator set the
-    env).  An EMPTY value at any layer disables explicitly — the test
+    ``dlaf_tpu.plan``).
+
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment wins: JAX reads it
+    itself and nothing is configured here.  Otherwise the directory is the
+    explicit ``base`` argument, else env ``DLAF_TPU_COMPILE_CACHE``, else
+    :data:`DEFAULT_COMPILE_CACHE`.  An EMPTY value disables — the test
     suite relies on this (serializing the largest 8-device shard_map
     executables can crash the cache backend; conftest pins the env to "").
-
-    The cache dir is partitioned by (platform, forced host device count,
-    host CPU fingerprint): deserializing an executable cached under a
-    different device topology can SEGFAULT inside
-    backend.deserialize_executable, and an XLA:CPU AOT blob from a host
-    with different ISA features loads with a SIGILL warning —
-    configurations/machines must never share a dir.
 
     ``min_compile_s`` (else env ``DLAF_TPU_COMPILE_CACHE_MIN_S``, default
     1.0) sets ``jax_persistent_cache_min_compile_time_secs`` — lower it to
     0 to persist even trivial executables (the acceptance test does).
-    Returns the partitioned dir in effect, or None when disabled."""
+    Returns the dir in effect, or None when disabled."""
     global _compile_cache_dir
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _compile_cache_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
+        return _compile_cache_dir
     if base is None:
-        base = os.environ.get("DLAF_TPU_COMPILE_CACHE")
-    if base is None:
-        base = default_base
+        base = os.environ.get("DLAF_TPU_COMPILE_CACHE", DEFAULT_COMPILE_CACHE)
     if not base:
         return None
-    base = os.path.expanduser(base)
+    cache_dir = os.path.abspath(os.path.expanduser(base))
     if min_compile_s is None:
         min_compile_s = float(os.environ.get("DLAF_TPU_COMPILE_CACHE_MIN_S", 1.0))
-
-    import re
-
-    plat = (os.environ.get("JAX_PLATFORMS") or "default").replace(",", "-")
-    m = re.search(r"host_platform_device_count=(\d+)",
-                  os.environ.get("XLA_FLAGS", ""))
-    cache_dir = os.path.join(
-        base, f"{plat}-{m.group(1) if m else 1}-{_host_fingerprint()}"
-    )
     if cache_dir == _compile_cache_dir and not force:
         return cache_dir
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", float(min_compile_s)
-        )
-        _reset_jax_compilation_cache()
-    except Exception:
-        return None
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", float(min_compile_s)
+    )
+    _reset_jax_compilation_cache()
     _compile_cache_dir = cache_dir
     return cache_dir
 
@@ -709,16 +676,13 @@ def _reset_jax_compilation_cache() -> None:
     a process that compiled anything before ``setup_compile_cache`` ran
     (late ``tune.initialize``, a probe jit at import time) would silently
     never persist.  reset_cache() is jax's own back-to-pristine hook."""
-    try:
-        from jax._src import compilation_cache as _cc
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:
-        pass
+    _cc.reset_cache()
 
 
 def compile_cache_dir() -> str | None:
-    """The partitioned persistent-cache dir in effect, or None (off)."""
+    """The persistent-cache dir in effect, or None (off)."""
     return _compile_cache_dir
 
 
@@ -728,11 +692,8 @@ def disable_compile_cache() -> None:
     global _compile_cache_dir
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _reset_jax_compilation_cache()
-    except Exception:
-        pass
+    jax.config.update("jax_compilation_cache_dir", None)
+    _reset_jax_compilation_cache()
     _compile_cache_dir = None
 
 

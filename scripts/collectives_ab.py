@@ -19,7 +19,7 @@ then ``nruns`` timed lookahead-POTRF factorizations with trace-time comms
 accounting.  Every tier's row carries GFlop/s next to the modeled wire
 split (payload / wire / overlapped) so the overlap win the pallas tier
 claims is printed beside the throughput it buys.  Rows land in ``--out``
-as JSON (the BENCH_r*.json shape: one dict per tier) and, with
+as JSON (one dict per tier) and, with
 ``--metrics``, in the obs.metrics JSONL stream ('run' + 'comms' + 'bench'
 records per tier) for scripts/report_metrics.py.
 

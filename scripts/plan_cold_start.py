@@ -24,9 +24,9 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -110,8 +110,20 @@ def main(argv=None) -> int:
     if args.as_child:
         return child(args)
 
-    cache_dir = args.cache_dir or tempfile.mkdtemp(prefix="dlaf_plan_cold_")
+    from dlaf_tpu import tune
+
+    # the first pass must start cold: the script empties only the fixed
+    # directory it owns and refuses a caller's directory that holds entries
+    if args.cache_dir:
+        cache_dir = args.cache_dir
+        if os.path.isdir(cache_dir) and os.listdir(cache_dir):
+            raise SystemExit(f"--cache-dir {cache_dir} is not empty; the cold "
+                             "pass needs an empty compile cache")
+    else:
+        cache_dir = os.path.join(tune.DEFAULT_COMPILE_CACHE, "plan_cold_start")
+        shutil.rmtree(cache_dir, ignore_errors=True)
     env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # the gate measures cache_dir
     env["DLAF_TPU_COMPILE_CACHE"] = cache_dir
     env["DLAF_TPU_COMPILE_CACHE_MIN_S"] = "0"
     env.setdefault("JAX_PLATFORMS", "cpu")

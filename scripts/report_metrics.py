@@ -274,12 +274,12 @@ def summarize(path: str) -> int:
         print(f"-- health events ({len(health)}):")
         for e, n in sorted(counts.items()):
             print(f"   {n:6d}  {e}")
-        # resilience roll-up: the bounded-time/restart story in four lines
-        # (deadlines that fired, probe outcomes, checkpoint traffic,
-        # degraded-mode dispatches) — see dlaf_tpu/resilience.py EVENTS
+        # resilience roll-up: the bounded-time/restart story in three
+        # lines (deadlines that fired, probe outcomes, checkpoint traffic)
+        # — see dlaf_tpu/resilience.py EVENTS
         res = {e: n for e, n in counts.items()
                if e in ("deadline_exceeded", "deadline_expired", "device_probe",
-                        "device_unresponsive", "fallback_dispatch",
+                        "device_unresponsive",
                         "checkpoint_written", "checkpoint_restored",
                         "checkpoint_config_mismatch")}
         if res:
@@ -294,8 +294,6 @@ def summarize(path: str) -> int:
                   f"{res.get('checkpoint_restored', 0)} restored"
                   + (f", {res['checkpoint_config_mismatch']} config drifts"
                      if res.get("checkpoint_config_mismatch") else ""))
-            if res.get("fallback_dispatch"):
-                print(f"   degraded-mode fallbacks: {res['fallback_dispatch']}")
         for r in health:
             detail = "  ".join(
                 f"{k}={r[k]}"

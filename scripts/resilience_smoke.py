@@ -88,16 +88,16 @@ def main(argv=None) -> int:
     except DeviceUnresponsiveError:
         expect(True, "watchdog classified the hang as device-unresponsive")
 
-    # 4. degraded-mode fallback dispatch
-    os.environ["DLAF_TPU_FALLBACK_PLATFORM"] = "cpu"
+    # 4. a dead probe raises through run_with_watchdog; the work never runs
+    ran = []
     try:
         with faults.hang(30.0):
-            out = resilience.run_with_watchdog(
-                lambda: 42, watchdog=resilience.DeviceWatchdog(budget_s=0.3)
+            resilience.run_with_watchdog(
+                lambda: ran.append(1), watchdog=resilience.DeviceWatchdog(budget_s=0.3)
             )
-        expect(out == 42, "fallback dispatch ran the workload")
-    finally:
-        del os.environ["DLAF_TPU_FALLBACK_PLATFORM"]
+        expect(False, "DeviceUnresponsiveError raised through run_with_watchdog")
+    except DeviceUnresponsiveError:
+        expect(not ran, "dead probe raised and the workload never ran")
 
     # 5. preemption-safe checkpoint/restart, bit-exact resume
     ref = cholesky_factorization("L", mk(), checkpoint_every=2).to_global()

@@ -404,9 +404,6 @@ def main() -> int:
 
     import jax
 
-    from dlaf_tpu.common.nativebuild import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     jax.config.update("jax_enable_x64", True)
 
     from dlaf_tpu.comm import multihost

@@ -244,8 +244,8 @@ def _dma_ring(n, slots, w, contributors, seed):
         oy, oh = ppe.dma_ring_exchange(yl, hl, "x", ("x",), True)
         return oy[None], oh[None]
 
-    f = jax.jit(coll.shard_map_compat(
-        fn, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=(P("x"), P("x"))
+    f = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=(P("x"), P("x")), check_vma=False
     ))
     oy, oh = f(y, h)
     oy, oh = np.asarray(oy), np.asarray(oh)
@@ -284,8 +284,8 @@ def test_dma_ring_single_rank_identity():
         )
         return oy[None], oh[None]
 
-    f = jax.jit(coll.shard_map_compat(
-        fn, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=(P("x"), P("x"))
+    f = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=(P("x"), P("x")), check_vma=False
     ))
     oy, oh = f(y[None], h[None])
     np.testing.assert_array_equal(np.asarray(oy)[0], np.asarray(y))

@@ -6,6 +6,9 @@ every trace-time knob must flip ``plan.trace_suffix()`` (and therefore
 every plan key) — a knob outside the key is a dead knob.
 """
 import json
+import os
+import subprocess
+import sys
 from contextlib import contextmanager
 
 import pytest
@@ -202,6 +205,27 @@ def test_sweep_cli_writes_loadable_profile(tmp_path):
 
 
 # ------------------------------------------------- zero-recompile cold start
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir_resolution(tmp_path, env_dir):
+    """Importing the miniapp harness keeps JAX's compile cache where
+    JAX_COMPILATION_CACHE_DIR says, as is; without it the cache is the
+    fixed in-checkout default (a fresh interpreter: the choice is made at
+    import)."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("DLAF_TPU_COMPILE_CACHE", "JAX_COMPILATION_CACHE_DIR")}
+    want = str(tmp_path / "x") if env_dir else tune.DEFAULT_COMPILE_CACHE
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    code = ("import jax, dlaf_tpu.miniapp.common; "
+            "print(jax.config.jax_compilation_cache_dir)")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip().splitlines()[-1] == want
+    assert tune.DEFAULT_COMPILE_CACHE == os.path.join(root, ".jax_cache")
+
 
 def test_zero_recompile_warm_cache(tmp_path, grid_1x1):
     """ISSUE 13 acceptance oracle, in-process: with the persistent

@@ -149,21 +149,21 @@ def test_watchdog_classifies_hang_as_unresponsive():
     assert any(e["event"] == "device_unresponsive" for e in ev)
 
 
-def test_fallback_dispatch_records_event(monkeypatch):
-    """With DLAF_TPU_FALLBACK_PLATFORM set and the primary device declared
-    dead, run_with_watchdog re-dispatches and records fallback_dispatch."""
-    monkeypatch.setenv("DLAF_TPU_FALLBACK_PLATFORM", "cpu")
+def test_dead_probe_skips_the_work():
+    """A dead probe records device_unresponsive and the wrapped work never
+    runs: nothing is re-dispatched to another platform."""
     wd = resilience.DeviceWatchdog(budget_s=0.3)
     wd._ensure_compiled()  # compile outside the faulted window
+    ran = []
     with health.capture_events() as ev:
-        with faults.hang(30.0):
-            out = resilience.run_with_watchdog(lambda: 41 + 1, watchdog=wd)
-    assert out == 42
-    assert any(e["event"] == "fallback_dispatch" for e in ev)
+        with pytest.raises(DeviceUnresponsiveError):
+            with faults.hang(30.0):
+                resilience.run_with_watchdog(lambda: ran.append(1), watchdog=wd)
+    assert not ran
+    assert any(e["event"] == "device_unresponsive" for e in ev)
 
 
-def test_no_fallback_reraises(monkeypatch):
-    monkeypatch.delenv("DLAF_TPU_FALLBACK_PLATFORM", raising=False)
+def test_dead_probe_raises_through_run_with_watchdog():
     wd = resilience.DeviceWatchdog(budget_s=0.3)
     wd._ensure_compiled()
     with pytest.raises(DeviceUnresponsiveError):

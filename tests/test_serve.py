@@ -151,7 +151,11 @@ def test_batched_posv_bitexact_vs_single(grid_1x1, uplo, dtype):
 
 def test_batched_potrf_bucket_padding_exact(grid_1x1):
     """An n that doesn't fill its bucket is padded with an identity block:
-    the leading n x n factor must still be bit-exact."""
+    the leading n x n factor matches the unpadded factorization to a few
+    ulps of the factor's scale.  Bit identity does not hold on XLA:CPU:
+    the padded and unpadded programs contract different tile counts, the
+    dots are blocked differently, and a handful of entries differ in the
+    last bits (3 of 1600, by 7.5e-9, at this seed)."""
     B, n, nb = 2, 40, 8
     a = _spd_batch(B, n, np.float32, seed=30)
     with _tuned(serve_buckets="64"):
@@ -163,7 +167,9 @@ def test_batched_potrf_bucket_padding_exact(grid_1x1):
     for i in range(B):
         mat = DistributedMatrix.from_global(grid_1x1, a[i], (nb, nb))
         fac, _ = cholesky_factorization("L", mat, return_info=True)
-        np.testing.assert_array_equal(np.asarray(fac.to_global()), l[i])
+        scale = np.finfo(np.float32).eps * np.abs(l[i]).max()
+        np.testing.assert_allclose(
+            np.asarray(fac.to_global()), l[i], rtol=0, atol=4 * scale)
 
 
 def test_batched_posv_single_rhs_squeeze():

@@ -200,8 +200,8 @@ def _consume_ring(n, slots, contributors, suppress, seed):
         )
         return ox[None], oy[None], oh[None]
 
-    f = jax.jit(coll.shard_map_compat(
-        fn, mesh=mesh, in_specs=(P("x"),) * 5, out_specs=(P("x"),) * 3
+    f = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(P("x"),) * 5, out_specs=(P("x"),) * 3, check_vma=False
     ))
     ox, oy, oh = (np.asarray(v) for v in f(x, cp, y, h, z))
     ref_update = jax.jit(
@@ -256,8 +256,8 @@ def test_dma_ring_consume_single_rank():
         )
         return ox[None], oy[None], oh[None]
 
-    f = jax.jit(coll.shard_map_compat(
-        fn, mesh=mesh, in_specs=(P("x"),) * 5, out_specs=(P("x"),) * 3
+    f = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(P("x"),) * 5, out_specs=(P("x"),) * 3, check_vma=False
     ))
     ox, oy, oh = (np.asarray(v)[0] for v in
                   f(x[None], cp[None], y[None], h[None], z[None]))
@@ -487,8 +487,8 @@ def test_her2k_suppress_mask_edge(comm_grids):
         )
         return ox[None], orp[None]
 
-    f = jax.jit(coll.shard_map_compat(
-        fn, mesh=mesh, in_specs=(P("x"),) * 5, out_specs=(P("x"),) * 2
+    f = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(P("x"),) * 5, out_specs=(P("x"),) * 2, check_vma=False
     ))
     ox, orp = (np.asarray(v) for v in f(x, cp, taken, have, suppress))
     merged = np.stack([taken[0, 0], taken[1, 1]])
