@@ -222,7 +222,8 @@ def device_chase_hh(
 
             kern = _plan.cached(
                 "band_chase", (dt, b, SB, K, n, n_pad, prec),
-                lambda: jax.jit(
+                lambda: _plan.jit(
+                    "band_chase",
                     partial(
                         _chase_block_kernel, n=n, n_pad=n_pad, b=b, SB=SB, K=K
                     ),

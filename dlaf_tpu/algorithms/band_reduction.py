@@ -169,6 +169,7 @@ def sbr_reduce(ab_host: np.ndarray, b1: int, b2: int, want_q: bool = True):
     import jax
     import jax.numpy as jnp
 
+    from dlaf_tpu.obs.trace import phase
     from dlaf_tpu.tune import get_tune_parameters, matmul_precision
 
     n = ab_host.shape[1]
@@ -196,22 +197,26 @@ def sbr_reduce(ab_host: np.ndarray, b1: int, b2: int, want_q: bool = True):
 
             kern = _plan.cached(
                 "sbr_chunk", (np.dtype(dt), b1, b2, n_pad, CH, K, prec, want_q),
-                lambda: jax.jit(
+                lambda: _plan.jit(
+                    "sbr_chunk",
                     partial(_sbr_chunk_kernel, b1=b1, b2=b2, CH=CH, K=K,
                             want_q=want_q),
                     donate_argnums=(0, 1),
                 ),
             )
-            if want_q:
-                q0 = jnp.zeros((CH, K + 1, b1, b1), dt) + eye
-            else:
-                q0 = jnp.zeros((0, 1, b1, b1), dt)
-            ab, qchunk = kern(ab, q0, jnp.asarray(s0))
+            with phase("band_stage/sbr/chunk"):
+                if want_q:
+                    q0 = jnp.zeros((CH, K + 1, b1, b1), dt) + eye
+                else:
+                    q0 = jnp.zeros((0, 1, b1, b1), dt)
+                ab, qchunk = kern(ab, q0, jnp.asarray(s0))
             if want_q:
                 # stage to host immediately: the device only ever holds
                 # one chunk of transform storage
-                out_chunks.append((s0, np.asarray(jax.device_get(qchunk))))
-    ab_np = np.asarray(jax.device_get(ab))
+                with phase("band_stage/sbr/readback"):
+                    out_chunks.append((s0, np.asarray(jax.device_get(qchunk))))
+    with phase("band_stage/sbr/readback"):
+        ab_np = np.asarray(jax.device_get(ab))
     ab2 = np.zeros((b2 + 2, n), dt)
     ab2[: b2 + 1] = ab_np[: b2 + 1, :n]
     return ab2, SbrTransforms(out_chunks, n, b1, b2)
@@ -309,7 +314,8 @@ def sbr_back_transform(tr: SbrTransforms, mat_e, out_cols: bool = False):
             rp = _plan.cached(
                 "sbr_bt_rowpad",
                 (grid.cache_key, tuple(e_cols.shape), n_pad, dt),
-                lambda: jax.jit(
+                lambda: _plan.jit(
+                    "sbr_bt_rowpad",
                     lambda gp: jnp.pad(gp, ((0, n_pad - gp.shape[0]), (0, 0))),
                     out_shardings=col_sh,
                 ),
@@ -328,7 +334,7 @@ def sbr_back_transform(tr: SbrTransforms, mat_e, out_cols: bool = False):
 
             # no donation: the stacked input cannot alias the col-sharded
             # padded output (different shapes), donating only warns
-            return jax.jit(pre, out_shardings=col_sh)
+            return _plan.jit("sbr_bt_pre", pre, out_shardings=col_sh)
 
         e_cols = _plan.cached(
             "sbr_bt_pre", (grid.cache_key, dist, n_pad, kpad, dt), build_pre
@@ -349,7 +355,8 @@ def sbr_back_transform(tr: SbrTransforms, mat_e, out_cols: bool = False):
                     out_specs=colspec,
                     check_vma=False,
                 )
-                return jax.jit(sm, out_shardings=col_sh, donate_argnums=(0,))
+                return _plan.jit("sbr_bt_apply", sm, out_shardings=col_sh,
+                                 donate_argnums=(0,))
 
             apply_fn = _plan.cached(
                 "sbr_bt_apply",

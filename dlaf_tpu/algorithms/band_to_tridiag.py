@@ -21,6 +21,7 @@ import numpy as np
 import scipy.linalg as sla
 
 from dlaf_tpu.matrix.matrix import DistributedMatrix
+from dlaf_tpu.obs.trace import phase
 
 
 @dataclass
@@ -65,7 +66,8 @@ def _gather_band_tiles(mat: DistributedMatrix):
         import jax
 
         rep = grid.replicated_sharding()
-        return jax.jit(
+        return _plan.jit(
+            "band_gather",
             lambda x: (x[idx["diag"]], x[idx["sub"]]),
             out_shardings=(rep, rep),
         )
@@ -77,7 +79,8 @@ def _gather_band_tiles(mat: DistributedMatrix):
         build,
     )
     diag, sub = fn(mat.data)
-    return np.asarray(diag), np.asarray(sub)
+    with phase("band_stage/readback"):
+        return np.asarray(diag), np.asarray(sub)
 
 
 def extract_band_host(mat: DistributedMatrix, band: int) -> np.ndarray:
@@ -238,11 +241,13 @@ def band_to_tridiagonal_hh_storage(ab: np.ndarray, band: int, dt, backend: str |
     else:
         from dlaf_tpu.native import band2trid_hh
 
-        out = band2trid_hh(ab, band)
+        with phase("band_stage/chase/native"):
+            out = band2trid_hh(ab, band)
     if out is None:
         return None
     d, e_raw, v_refl, taus = out
-    norm = _normalize_phases(d, e_raw, None, np.dtype(dt))
+    with phase("band_stage/chase/phases"):
+        norm = _normalize_phases(d, e_raw, None, np.dtype(dt))
     return norm.d, norm.e, norm.phases, v_refl, taus, band
 
 

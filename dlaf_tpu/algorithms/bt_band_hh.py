@@ -145,8 +145,8 @@ def _apply_fn(n_pad, k, w, g, G, dtype, dist_key=None, dist=None, sharding=None,
             return layout.pack(layout.pad_global(eg, dist), dist)
 
         if sharding is not None:
-            return jax.jit(run, out_shardings=sharding)
-        return jax.jit(run)
+            return _plan.jit("bt_band_apply", run, out_shardings=sharding)
+        return _plan.jit("bt_band_apply", run)
 
     return _plan.cached(
         "bt_band_apply",
@@ -183,6 +183,7 @@ def bt_band_to_tridiagonal_hh_dist(
     from dlaf_tpu.comm import collectives as coll
     from dlaf_tpu.comm.grid import COL_AXIS, ROW_AXIS
     from dlaf_tpu.matrix import layout
+    from dlaf_tpu.obs.trace import phase
 
     from dlaf_tpu.tune import get_tune_parameters, matmul_precision
 
@@ -193,27 +194,29 @@ def bt_band_to_tridiagonal_hh_dist(
     dt = np.dtype(mat_e.dtype)
     group_size = _resolve_group_size(group_size)
     has_refl = v_refl.shape[0] > 0 and n > 2 and k > 0 and band > 1
-    if has_refl:
-        g = max(1, min(group_size, band, n - 2))
-        groups, w = hh_schedule(n, band, g)
-        V_all, tau_all, offs = _build_factors(v_refl, taus, groups, w, g, band, dt)
-        G = len(groups)
-    else:
-        if dt.kind != "c" or n == 0 or k == 0:
-            return mat_e
-        g, w, G = 1, 1, 0
-        V_all = np.zeros((0, 1, 1), dt)
-        tau_all = np.ones((0, 1), dt)
-        offs = np.zeros(0, np.int32)
-    n_pad = max(n, w)
+    if not has_refl and (dt.kind != "c" or n == 0 or k == 0):
+        return mat_e
+    with phase("bt_band/factors"):
+        if has_refl:
+            g = max(1, min(group_size, band, n - 2))
+            groups, w = hh_schedule(n, band, g)
+            V_all, tau_all, offs = _build_factors(v_refl, taus, groups, w, g, band, dt)
+            G = len(groups)
+        else:
+            g, w, G = 1, 1, 0
+            V_all = np.zeros((0, 1, 1), dt)
+            tau_all = np.ones((0, 1), dt)
+            offs = np.zeros(0, np.int32)
+        n_pad = max(n, w)
+        ph = np.ones(n_pad, dt)
+        if dt.kind == "c":
+            ph[:n] = phases.astype(dt)
+        factors = tuple(jnp.asarray(a) for a in (V_all, tau_all, offs, ph))
     Ptot = grid.grid_size.count()
     kloc = -(-k // Ptot)
     kpad = kloc * Ptot
     mesh = grid.mesh
     colspec = P(None, (ROW_AXIS, COL_AXIS))
-    ph = np.ones(n_pad, dt)
-    if dt.kind == "c":
-        ph[:n] = phases.astype(dt)
     prec = get_tune_parameters().eigensolver_matmul_precision
     from dlaf_tpu.plan import core as _plan
 
@@ -244,8 +247,9 @@ def bt_band_to_tridiagonal_hh_dist(
         )
         # donation only helps when output aliases input (stacked -> stacked);
         # the col-sharded output can't alias, donating would only warn
-        return jax.jit(
-            run, out_shardings=out_sh, donate_argnums=() if out_cols else (0,)
+        return _plan.jit(
+            "bt_band_dist", run, out_shardings=out_sh,
+            donate_argnums=() if out_cols else (0,),
         )
 
     fn = _plan.cached(
@@ -253,14 +257,8 @@ def bt_band_to_tridiagonal_hh_dist(
         (grid.cache_key, dist, n_pad, kpad, w, g, G, dt, prec, out_cols),
         build,
     )
-    with matmul_precision(prec):
-        data = fn(
-            mat_e.data,
-            jnp.asarray(V_all),
-            jnp.asarray(tau_all),
-            jnp.asarray(offs),
-            jnp.asarray(ph),
-        )
+    with matmul_precision(prec), phase("bt_band/apply"):
+        data = fn(mat_e.data, *factors)
     if out_cols:
         from dlaf_tpu.matrix.colpanels import ColPanels
 

@@ -151,7 +151,7 @@ def _bt_r2b_cols(cols, mat_band: DistributedMatrix, taus: jax.Array):
             return layout.pack(layout.pad_global(gp[:n, :k], dist), dist)
 
         # no donation: the col-sharded input cannot alias the stacked output
-        return jax.jit(run, out_shardings=grid.stacked_sharding())
+        return _plan.jit("bt_r2b_cols", run, out_shardings=grid.stacked_sharding())
 
     fn = _plan.cached(
         "bt_r2b_cols",
@@ -207,7 +207,7 @@ def bt_reduction_to_band(
     prec = get_tune_parameters().eigensolver_matmul_precision
     def build():
         kern = partial(_bt_r2b_kernel, g_a=g_a, g_e=g_e, n_panels=n_panels, band=band)
-        return coll.spmd(mat_e.grid, kern, donate_argnums=(2,))
+        return coll.spmd(mat_e.grid, kern, donate_argnums=(2,), name="bt_r2b")
 
     fn = _plan.cached(
         "bt_r2b", (mat_e.grid.cache_key, g_a, g_e, n_panels, band, prec), build

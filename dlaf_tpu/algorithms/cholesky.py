@@ -510,8 +510,9 @@ def _compiled(grid, g: _spmd.Geometry, uplo: str, variant: str = "bucketed",
                 partial(kern_fn, g=g, want_info=True),
                 donate_argnums=(0,),
                 out_specs=(P(ROW_AXIS, COL_AXIS), P()),
+                name="cholesky",
             )
-        return coll.spmd(grid, partial(kern_fn, g=g), donate_argnums=(0,))
+        return coll.spmd(grid, partial(kern_fn, g=g), donate_argnums=(0,), name="cholesky")
 
     return _plan.cached("cholesky", (grid.cache_key, g, uplo, variant, want_info),
                         build)
@@ -533,7 +534,7 @@ def _compiled_range(grid, g: _spmd.Geometry):
             out_specs=(spec, P()),
             check_vma=False,
         )
-        return jax.jit(sm, donate_argnums=(0,))
+        return _plan.jit("cholesky_range", sm, donate_argnums=(0,))
 
     return _plan.cached("cholesky_range", (grid.cache_key, g), build)
 
@@ -590,7 +591,6 @@ def _cholesky_single_device(uplo: str, mat_a: DistributedMatrix) -> DistributedM
     dist = mat_a.dist
 
     def build():
-        @jax.jit
         def run(x):
             g_ = layout.unpad_global(layout.unpack(x, dist), dist)
             if uplo == t.LOWER:
@@ -603,7 +603,7 @@ def _cholesky_single_device(uplo: str, mat_a: DistributedMatrix) -> DistributedM
                 out = fac + jnp.tril(g_, -1)
             return layout.pack(layout.pad_global(out, dist), dist)
 
-        return run
+        return _plan.jit("cholesky_local", run)
 
     fn = _plan.cached("cholesky_local", (dist, np.dtype(mat_a.dtype), uplo), build)
     with blas3_precision():

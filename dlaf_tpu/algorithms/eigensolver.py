@@ -66,9 +66,19 @@ def hermitian_eigensolver(
         # lower-storage pipeline on the mirrored matrix
         mat_a = mutil.extract_triangle(mutil.hermitize(mat_a, "U"), "L")
         uplo = t.LOWER
-    grid = mat_a.grid
-    if backend == "auto" and grid.grid_size.count() == 1 and mat_a.size.rows > 0:
+    if backend == "auto" and mat_a.grid.grid_size.count() == 1 and mat_a.size.rows > 0:
         return _eigh_single_device(mat_a, spectrum)
+    from dlaf_tpu.obs.trace import phase
+
+    # one span over the pipeline: the host time between its stages is
+    # named by the library on a profile
+    with phase("heev"):
+        return _pipeline(mat_a, spectrum)
+
+
+def _pipeline(mat_a: DistributedMatrix, spectrum) -> EigResult:
+    """The distributed band-reduction pipeline on lower storage."""
+    grid = mat_a.grid
     nb = mat_a.block_size.rows
     n = mat_a.size.rows
     band = get_band_size(nb)
@@ -138,7 +148,7 @@ def hermitian_eigensolver(
             "flops instead of O(N^2 b). Build the native library (needs g++) "
             "or set DLAF_TPU_BAND_CHASE_BACKEND=device.",
             RuntimeWarning,
-            stacklevel=2,
+            stacklevel=3,
         )
     b2t = band_to_tridiagonal(band_mat, band=band)
     evals, e_tri = tridiagonal_eigensolver(
@@ -252,23 +262,21 @@ def _eigh_single_device(mat_a: DistributedMatrix, spectrum) -> EigResult:
     from dlaf_tpu.plan import core as _plan
 
     def build_eigh():
-        @jax.jit
         def run(x):
             g = layout.unpad_global(layout.unpack(x, dist), dist)
             full = jnp.tril(g) + jnp.swapaxes(jnp.tril(g, -1), -1, -2).conj()
             return jnp.linalg.eigh(full)  # dense (w, v), on device
 
-        return run
+        return _plan.jit("eigh_local", run)
 
     def build_pack():
-        @jax.jit
         def packrun(w, v):
             if sl is not None:
                 w = w[sl[0] : sl[1] + 1]
                 v = v[:, sl[0] : sl[1] + 1]
             return w, layout.pack(layout.pad_global(v, out_dist), out_dist)
 
-        return packrun
+        return _plan.jit("eigh_local_pack", packrun)
 
     eigh_fn = _plan.cached(
         "eigh_local", (dist, np.dtype(mat_a.dtype)), build_eigh

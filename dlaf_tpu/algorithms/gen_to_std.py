@@ -156,14 +156,13 @@ def _tile_mask(mat: DistributedMatrix, rel: str) -> DistributedMatrix:
     def build():
         d = mat.dist
 
-        @jax.jit
         def run(x):
             gi, gj = mutil._global_element_grids(d)
             ti, tj = gi // d.block_size.rows, gj // d.block_size.cols
             keep = (ti > tj) if rel == "lt" else (ti == tj)
             return jnp.where(keep, x, jnp.zeros_like(x))
 
-        return run
+        return _plan.jit("hegst_tmask", run)
 
     fn = _plan.cached("hegst_tmask", (rel, mat.dist, np.dtype(mat.dtype)), build)
     return mat.like(fn(mat.data))
@@ -185,6 +184,7 @@ def _gen_to_std_fused(mat_a_full: DistributedMatrix, mat_b_l: DistributedMatrix)
             mat_a_full.grid,
             partial(_hegst_phase_a_kernel, g=g),
             donate_argnums=(0,),
+            name="hegst_phase_a",
         )
 
     fn = _plan.cached("hegst_phase_a", (mat_a_full.grid.cache_key, g), build)

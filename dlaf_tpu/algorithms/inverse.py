@@ -256,7 +256,6 @@ def _trtri_single_device(uplo: str, diag: str, mat_a: DistributedMatrix) -> Dist
     dist = mat_a.dist
 
     def build():
-        @jax.jit
         def run(x):
             g_ = layout.unpad_global(layout.unpack(x, dist), dist)
             eye = jnp.eye(g_.shape[0], dtype=g_.dtype)
@@ -268,7 +267,7 @@ def _trtri_single_device(uplo: str, diag: str, mat_a: DistributedMatrix) -> Dist
                 out = jnp.triu(inv) + jnp.tril(g_, -1)
             return layout.pack(layout.pad_global(out, dist), dist)
 
-        return run
+        return _plan.jit("trtri_local", run)
 
     fn = _plan.cached("trtri_local", (dist, str(mat_a.dtype), uplo, diag), build)
     with blas3_precision():
@@ -293,7 +292,8 @@ def triangular_inverse(uplo: str, diag: str, mat_a: DistributedMatrix) -> Distri
             _trtri_lower_bucketed_kernel if uplo == t.LOWER else _trtri_upper_bucketed_kernel
         )
         return coll.spmd(
-            mat_a.grid, partial(kern_fn, g=g, diag=diag), donate_argnums=(0,)
+            mat_a.grid, partial(kern_fn, g=g, diag=diag), donate_argnums=(0,),
+            name="trtri",
         )
 
     fn = _plan.cached("trtri", (mat_a.grid.cache_key, uplo, diag, g), build)

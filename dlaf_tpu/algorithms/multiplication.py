@@ -185,7 +185,6 @@ def _run_dense_local(mat_a, mat_b, mat_c, opa, opb, alpha, beta, structure, diag
     def build():
         from dlaf_tpu.matrix import layout
 
-        @jax.jit
         def run(xa, xb, xc):
             ga = layout.unpad_global(layout.unpack(xa, da), da)
             gb = layout.unpad_global(layout.unpack(xb, db), db)
@@ -200,7 +199,7 @@ def _run_dense_local(mat_a, mat_b, mat_c, opa, opb, alpha, beta, structure, diag
             out = jnp.asarray(alpha, gc.dtype) * prod + jnp.asarray(beta, gc.dtype) * gc
             return layout.pack(layout.pad_global(out.astype(gc.dtype), dc), dc)
 
-        return run
+        return _plan.jit("gemm_local", run)
 
     fn = _plan.cached(
         "gemm_local",
@@ -227,7 +226,7 @@ def _run_summa(mat_a, mat_b, mat_c, opa, opb, alpha, beta, structure, diag, kt):
             _summa_kernel, g_a=g_a, g_b=g_b, g_c=g_c, opa=opa, opb=opb,
             alpha=alpha, beta=beta, structure=structure, diag=diag, kt=kt,
         )
-        return coll.spmd(mat_c.grid, kern, donate_argnums=(2,))
+        return coll.spmd(mat_c.grid, kern, donate_argnums=(2,), name="summa")
 
     fn = _plan.cached(
         "summa",
@@ -367,7 +366,7 @@ def _run_summa_right(mat_a, mat_b, mat_c, opa, alpha, structure, diag, beta=0.0)
             _summa_right_kernel, g_a=g_a, g_b=g_b, g_c=g_c, opa=opa,
             alpha=alpha, beta=beta, structure=structure, diag=diag, kt=kt,
         )
-        return coll.spmd(mat_c.grid, kern, donate_argnums=(2,))
+        return coll.spmd(mat_c.grid, kern, donate_argnums=(2,), name="summa_right")
 
     fn = _plan.cached(
         "summa_right",
@@ -542,7 +541,7 @@ def general_sub_multiplication(
             alpha=alpha, beta=beta,
         )
         return coll.spmd(
-            mat_c.grid, kern, donate_argnums=() if aliased else (2,)
+            mat_c.grid, kern, donate_argnums=() if aliased else (2,), name="sub_gemm"
         )
 
     fn = _plan.cached(
@@ -567,7 +566,6 @@ def _sub_gemm_local(alpha, a_ref, b_ref, beta, c_ref):
     def build():
         from dlaf_tpu.matrix import layout
 
-        @jax.jit
         def run(xa, xb, xc):
             ga = layout.unpad_global(layout.unpack(xa, da), da)
             gb = layout.unpad_global(layout.unpack(xb, db), db)
@@ -581,7 +579,7 @@ def _sub_gemm_local(alpha, a_ref, b_ref, beta, c_ref):
             gc = lax.dynamic_update_slice(gc, new.astype(gc.dtype), oc)
             return layout.pack(layout.pad_global(gc, dc), dc)
 
-        return run
+        return _plan.jit("sub_gemm_local", run)
 
     fn = _plan.cached(
         "sub_gemm_local",

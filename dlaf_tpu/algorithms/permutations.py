@@ -118,7 +118,7 @@ def _ring_fn(grid, dist, coord):
             out_specs=stacked,
             check_vma=False,
         )
-        return jax.jit(sm)
+        return _plan.jit("permute_ring", sm)
 
     return _plan.cached("permute_ring", (grid.cache_key, g, coord), build)
 

@@ -300,7 +300,7 @@ def _compiled_range(grid, g: _spmd.Geometry, band: int, prec: str):
             out_specs=(spec, P()),
             check_vma=False,
         )
-        return jax.jit(sm, donate_argnums=(0,))
+        return _plan.jit("red2band_range", sm, donate_argnums=(0,))
 
     return _plan.cached("red2band_range", (grid.cache_key, g, band, prec), build)
 
@@ -422,7 +422,7 @@ def reduction_to_band(
         return out, taus
     def build():
         kern = partial(_red2band_kernel, g=g, n_panels=n_panels, band=band)
-        return coll.spmd(mat_a.grid, kern, donate_argnums=(0,))
+        return coll.spmd(mat_a.grid, kern, donate_argnums=(0,), name="red2band")
 
     fn = _plan.cached(
         "red2band", (mat_a.grid.cache_key, g, band, n_panels, prec), build

@@ -376,14 +376,13 @@ def _trsm_single_device(side, uplo, op, diag, alpha, mat_a, mat_b):
     da, db = mat_a.dist, mat_b.dist
 
     def build():
-        @jax.jit
         def run(xa, xb):
             ga = layout.unpad_global(layout.unpack(xa, da), da)
             gb = layout.unpad_global(layout.unpack(xb, db), db)
             out = t.trsm(side, uplo, op, diag, jnp.asarray(alpha, gb.dtype), ga, gb)
             return layout.pack(layout.pad_global(out, db), db)
 
-        return run
+        return _plan.jit("trsm_local", run)
 
     fn = _plan.cached(
         "trsm_local",
@@ -455,7 +454,7 @@ def triangular_solver(
 
     def build():
         kern = partial(kern_fn, g_a=g_a, g_b=g_b, uplo=uplo, op=op, diag=diag, alpha=alpha)
-        return coll.spmd(mat_b.grid, kern, donate_argnums=(1,))
+        return coll.spmd(mat_b.grid, kern, donate_argnums=(1,), name="trsm")
 
     fn = _plan.cached(
         "trsm",

@@ -65,16 +65,18 @@ from dlaf_tpu.comm import collectives as coll
 from dlaf_tpu.comm.grid import COL_AXIS, ROW_AXIS, Grid
 from dlaf_tpu.matrix.distribution import Distribution
 from dlaf_tpu.matrix.matrix import DistributedMatrix, place
+from dlaf_tpu.obs.trace import phase as _phase
 from dlaf_tpu.obs.trace import scope as _scope
+from dlaf_tpu.plan import core as _plancache
 
 _BOTH = (ROW_AXIS, COL_AXIS)
 
 
-def _spmd(grid, fn, in_specs, out_specs, donate=()):
+def _spmd(op, grid, fn, in_specs, out_specs, donate=()):
     sm = jax.shard_map(
         fn, mesh=grid.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
-    return jax.jit(sm, donate_argnums=donate)
+    return _plancache.jit(op, sm, donate_argnums=donate)
 
 
 def _plan(n: int, nb: int, leaf_target: int):
@@ -629,12 +631,11 @@ def tridiag_dc_distributed(
         grid.cache_key, n_pad, s0, nb, str(dt), prec,
         bool(getattr(get_tune_parameters(), "dc_secular_pallas", False)),
     )
-    from dlaf_tpu.plan import core as _plancache
-
     nloc = -(-nleaf // Ptot)
 
     def build_leaf():
         return _spmd(
+            "dc_leaf",
             grid,
             partial(_leaf_kernel, g=g, s0=s0, nleaf=nleaf, nloc=nloc, dt=dt),
             in_specs=(P(_BOTH),),
@@ -642,7 +643,8 @@ def tridiag_dc_distributed(
         )
 
     leaf_fn = _plancache.cached("dc_leaf", key0, build_leaf)
-    lam0, q0 = _leaf_eigh(d_mod, e_pad, s0, nleaf, nloc * Ptot, rdt)
+    with _phase("tridiag/leaves"):
+        lam0, q0 = _leaf_eigh(d_mod, e_pad, s0, nleaf, nloc * Ptot, rdt)
     x = leaf_fn(place(q0, NamedSharding(grid.mesh, P(_BOTH))))
     lam = jnp.asarray(lam0)
 
@@ -654,6 +656,7 @@ def tridiag_dc_distributed(
         beta_l = jnp.asarray(e_pad[mids - 1])
         def build_params(S=S, B=B, RPD=RPD):
             return _spmd(
+                "dc_params",
                 grid,
                 partial(
                     _params_kernel, g=g, S=S, B=B, n_pad=n_pad, RPD=RPD,
@@ -667,9 +670,11 @@ def tridiag_dc_distributed(
         with matmul_precision(prec):
             prm = params_fn(x, lam, beta_l)
         lam = prm[0]
-        has_rot = bool(prm[15])
+        with _phase("tridiag/readback"):
+            has_rot = bool(prm[15])
         def build_gemm(S=S, B=B, has_rot=has_rot):
             return _spmd(
+                "dc_gemm",
                 grid,
                 partial(_level_kernel, g=g, S=S, B=B, n_pad=n_pad, dt=dt, rot=has_rot),
                 in_specs=tuple([stacked] + [rep] * 14),
@@ -681,7 +686,8 @@ def tridiag_dc_distributed(
         with matmul_precision(prec):
             x = gemm_fn(x, *prm[1:15])
 
-    w = np.asarray(lam)[:n]
+    with _phase("tridiag/readback"):
+        w = np.asarray(lam)[:n]
     mat = DistributedMatrix(dist, grid, x)
     il, iu = (0, n - 1) if spectrum is None else spectrum
     out = mutil.sub_matrix(mat, (0, il), (n, iu - il + 1)) if (n_pad != n or spectrum is not None) else mat

@@ -1,6 +1,6 @@
 """dlaf_tpu.analysis — project-specific SPMD/trace-safety linter.
 
-``python -m dlaf_tpu.analysis [paths]`` runs four AST rule families over
+``python -m dlaf_tpu.analysis [paths]`` runs five AST rule families over
 the tree.  The analyzer itself is stdlib ``ast`` only (no third-party
 deps, nothing is imported or executed from the linted files):
 
@@ -13,6 +13,8 @@ deps, nothing is imported or executed from the linted files):
   inside ``jit`` / ``shard_map`` / ``pallas_call`` regions.
 * **DLAF004** serve lock discipline — no blocking work or future
   completion while holding a serve-layer lock.
+* **DLAF005** program names — no ``jax.jit`` of a ``partial`` or a
+  ``lambda`` (an unnamed program); ``plan.jit(op, fun)`` names it.
 
 See docs/LINTING.md for the rule catalog, the shipped bugs each rule
 encodes, and the suppression / baseline workflow.

@@ -402,7 +402,7 @@ def transpose_panel_rows(rp, nr_col_tiles, ltr: int):
     return _panel_exchange(taken, have, COL_AXIS)
 
 
-def spmd(grid, fn, static_argnums=(), donate_argnums=(), out_specs=None):
+def spmd(grid, fn, static_argnums=(), donate_argnums=(), out_specs=None, name="spmd"):
     """jit(shard_map(fn)) over the grid mesh with stacked-layout specs.
 
     ``fn`` receives each array argument as the device-local block with the
@@ -414,6 +414,9 @@ def spmd(grid, fn, static_argnums=(), donate_argnums=(), out_specs=None):
     auxiliary rank-replicated scalars next to the matrix — e.g. the
     Cholesky ``info`` code — pass ``(P('r', 'c'), P())``; every rank must
     compute the identical value for a ``P()`` output.
+
+    ``name``: the program is ``jit_<name>`` (``plan.jit``); the plan
+    cache's builders pass their op.
     """
     P = jax.sharding.PartitionSpec
     spec = P(ROW_AXIS, COL_AXIS)
@@ -422,7 +425,9 @@ def spmd(grid, fn, static_argnums=(), donate_argnums=(), out_specs=None):
         out_specs=spec if out_specs is None else out_specs,
         check_vma=False,
     )
-    return jax.jit(sm, static_argnums=static_argnums, donate_argnums=donate_argnums)
+    from dlaf_tpu.plan import core as _plan
+
+    return _plan.jit(name, sm, static_argnums=static_argnums, donate_argnums=donate_argnums)
 
 
 def local(x):

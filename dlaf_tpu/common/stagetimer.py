@@ -34,10 +34,6 @@ def stop() -> dict:
     return t
 
 
-def collecting() -> bool:
-    return _times is not None
-
-
 def barrier(*trees) -> None:
     """Block until the given jax values are ready — only while collecting
     (stage attribution needs a sync point; otherwise async dispatch lets a

@@ -53,7 +53,7 @@ def pack_to_matrix(cp: ColPanels) -> DistributedMatrix:
         def post(gp):
             return layout.pack(layout.pad_global(gp[:n, :k], dist), dist)
 
-        return jax.jit(post, out_shardings=grid.stacked_sharding())
+        return _plan.jit("colpanels_pack", post, out_shardings=grid.stacked_sharding())
 
     fn = _plan.cached(
         "colpanels_pack",

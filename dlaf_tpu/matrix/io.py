@@ -90,7 +90,8 @@ def _row_fetch_fn(grid: Grid, shape, dtype):
             )
             return row[0, :, 0]
 
-        return jax.jit(
+        return _plan.jit(
+            "io_row_fetch",
             fetch,
             in_shardings=(grid.stacked_sharding(), None, None),
             out_shardings=grid.replicated_sharding(),
@@ -181,7 +182,8 @@ def _row_update_fn(grid: Grid, shape, dtype):
                 x, row[None, :, None], (rr, z, li, z, z, z)
             )
 
-        return jax.jit(
+        return _plan.jit(
+            "io_row_update",
             upd,
             donate_argnums=(0,),
             in_shardings=(

@@ -60,7 +60,8 @@ def _replicate_fn(grid: Grid):
     return _plan.cached(
         "replicate",
         (grid.cache_key,),
-        lambda: jax.jit(lambda v: v, out_shardings=grid.replicated_sharding()),
+        lambda: _plan.jit("replicate", lambda v: v,
+                          out_shardings=grid.replicated_sharding()),
     )
 
 

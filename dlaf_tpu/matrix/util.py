@@ -80,7 +80,10 @@ def transpose(mat: DistributedMatrix, conj: bool = False) -> DistributedMatrix:
     # out_shardings (not a post-hoc device_put): the compiled program ends in
     # the resharding collective itself, which also works on multi-process
     # worlds where device_put cannot reach non-addressable devices
-    fn = jax.jit(
+    from dlaf_tpu.plan import core as _plan
+
+    fn = _plan.jit(
+        "transpose",
         partial(_transpose_data, dist=d, dist_t=dist_t, conj=conj),
         out_shardings=mat.grid.stacked_sharding(),
     )

@@ -47,7 +47,8 @@ def _reshard_rolled(data, src_grid, dst_grid, roll):
     fn = _plan.cached(
         "window_reshard",
         (src_grid.cache_key, roll, data.shape, str(data.dtype)),
-        lambda: jax.jit(
+        lambda: _plan.jit(
+            "window_reshard",
             lambda x: jnp.roll(x, (sr, sc), (0, 1)),
             out_shardings=src_grid.stacked_sharding(),
         ),
@@ -217,7 +218,7 @@ def window_extract(mat: DistributedMatrix, origin, size) -> DistributedMatrix:
             m_out=m, n_out=n,
             mt_par=mat.dist.nr_tiles.rows, nt_par=mat.dist.nr_tiles.cols,
         )
-        return coll.spmd(mat.grid, kern)
+        return coll.spmd(mat.grid, kern, name="window_extract")
 
     fn = _plan.cached(
         "window_extract", (mat.grid.cache_key, mat.dist, r0, c0, m, n), build
@@ -287,7 +288,7 @@ def window_update(mat: DistributedMatrix, origin, win: DistributedMatrix) -> Dis
             mt_win=win.dist.nr_tiles.rows, nt_win=win.dist.nr_tiles.cols,
             ltr_mid=mat.dist.local_slots.rows,
         )
-        return coll.spmd(mat.grid, kern, donate_argnums=(0,))
+        return coll.spmd(mat.grid, kern, donate_argnums=(0,), name="window_update")
 
     fn = _plan.cached(
         "window_update", (mat.grid.cache_key, mat.dist, win.dist, r0, c0), build

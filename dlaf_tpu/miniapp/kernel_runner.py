@@ -20,6 +20,7 @@ import numpy as np
 import dlaf_tpu.testing as tu
 from dlaf_tpu.miniapp.common import DTYPES
 from dlaf_tpu.ops import tile as t
+from dlaf_tpu.plan import core as _plan
 
 
 def _time(fn, *args, nreps: int) -> float:
@@ -64,7 +65,7 @@ def main(argv=None):
     taus = jnp.asarray(np.full(nb, 1.5, np.dtype(dtype)))
 
     runners = {}
-    runners["potrf"] = (jax.jit(lambda x: t.potrf(x)), (h,), nb**3 / 3)
+    runners["potrf"] = (_plan.jit("potrf", lambda x: t.potrf(x)), (h,), nb**3 / 3)
     try:
         from dlaf_tpu.ops import pallas_potrf
 
@@ -73,7 +74,8 @@ def main(argv=None):
     except Exception:
         pass
     runners["trsm"] = (
-        jax.jit(lambda lk, b: t.trsm(t.RIGHT, t.LOWER, t.CONJ_TRANS, t.NON_UNIT, 1.0, lk, b)),
+        _plan.jit("trsm", lambda lk, b: t.trsm(t.RIGHT, t.LOWER, t.CONJ_TRANS, t.NON_UNIT,
+                                               1.0, lk, b)),
         (l, panel),
         bt * nb**3,
     )
@@ -129,14 +131,14 @@ def main(argv=None):
 
         runners["secular_xla"] = (_secular_xla, (), 2.0 * ITERS * K * S)
     runners["gemm"] = (
-        jax.jit(lambda x, y: jnp.einsum("iab,jcb->ijac", x, y)),
+        _plan.jit("gemm", lambda x, y: jnp.einsum("iab,jcb->ijac", x, y)),
         (panel, panel),
         2 * bt * bt * nb**3,
     )
     from dlaf_tpu.algorithms.reduction_to_band import _t_factor
 
     runners["tfactor"] = (
-        jax.jit(lambda vv, tt: _t_factor(vv.reshape(-1, nb), tt, nb)),
+        _plan.jit("tfactor", lambda vv, tt: _t_factor(vv.reshape(-1, nb), tt, nb)),
         (v, taus),
         bt * nb**3,  # dominated by V^H V
     )

@@ -7,7 +7,9 @@ three independent, individually opt-in pieces:
   ``obs.trace``    named phases — ``jax.named_scope`` inside jitted kernel
                    bodies (visible in compiled-HLO op metadata and profiler
                    timelines) plus host-level ``TraceAnnotation`` phases with
-                   an optional phase log for tests.
+                   an optional phase log for tests; every program compile or
+                   load is charged to the open phase (:func:`program_loads`)
+                   and spans it on the profiler timeline.
   ``obs.comms``    trace-time accounting of every collective in
                    ``comm.collectives`` — message counts and analytic byte
                    volumes per (kind, dtype, axis) without touching the HLO.
@@ -43,10 +45,10 @@ import contextlib
 
 from dlaf_tpu.common import stagetimer as _st
 from dlaf_tpu.obs import comms, flight, metrics, spans, telemetry, trace
-from dlaf_tpu.obs.trace import phase, scope
+from dlaf_tpu.obs.trace import phase, program_loads, scope
 
 __all__ = ["comms", "flight", "metrics", "spans", "telemetry", "trace",
-           "phase", "scope", "stage"]
+           "phase", "program_loads", "scope", "stage"]
 
 
 @contextlib.contextmanager

@@ -87,10 +87,6 @@ def stop() -> dict:
     return acc
 
 
-def collecting() -> bool:
-    return _acc is not None
-
-
 def snapshot() -> dict:
     """Copy of the running accumulation without stopping it."""
     return {k: list(v) for k, v in (_acc or {}).items()}

@@ -16,8 +16,8 @@ to each host's working directory.
 
 Off by default and free when off: :func:`emit` is one ``is None`` test
 per sink (emitter + flight-recorder tee).  Compile-time records ride
-``jax.monitoring`` listeners that are registered once on first
-:func:`enable` and forward only while an emitter is active.
+``plan.core``'s one set of ``jax.monitoring`` listeners (registered at the
+latest on first :func:`enable`), forwarded only while an emitter is active.
 
 Schema history: ``/1`` is the original record set; ``/2`` adds the
 ``span`` (request-scoped tracing, ``obs.spans``) and ``flight`` (crash
@@ -82,7 +82,6 @@ REQUIRED_FIELDS: dict = {
 }
 
 _emitter = None
-_listeners_registered = False
 # Optional secondary sink (the flight recorder's ring tap): called as
 # _tee(kind, fields) for every record emitted, whether or not a JSONL
 # emitter is active.  None = off (the common case; emit() stays two
@@ -153,12 +152,14 @@ def _jsonable(x):
 
 
 def enable(path: str) -> MetricsEmitter:
-    """Open the metrics stream at ``path`` (closing any previous one) and
-    hook the jax.monitoring compile listeners (idempotent)."""
+    """Open the metrics stream at ``path`` (closing any previous one); the
+    compile records come from ``plan.core``'s jax.monitoring listeners."""
+    from dlaf_tpu.plan import core as _plan
+
     global _emitter
     if _emitter is not None:
         _emitter.close()
-    _register_listeners()
+    _plan.register_monitoring()
     _emitter = MetricsEmitter(path)
     return _emitter
 
@@ -200,13 +201,6 @@ def add_tap(fn) -> None:
     _taps = taps
 
 
-def remove_tap(fn) -> None:
-    """Unregister a tap installed by :func:`add_tap` (no-op if absent)."""
-    global _taps
-    taps = [t for t in (_taps or ()) if t is not fn]
-    _taps = taps or None
-
-
 def sinking() -> bool:
     """True when at least one sink would receive an emitted record."""
     return _emitter is not None or _tee is not None or _taps is not None
@@ -221,30 +215,19 @@ def close() -> None:
     em.close()
 
 
-def _register_listeners() -> None:
-    """Forward jax.monitoring compile/cache events into the active stream.
+def forward_compile(event: str, duration: float) -> None:
+    """A ``jax.monitoring`` duration event (``plan.core``'s listener hands
+    each one on): compile durations become ``compile`` records while a
+    stream is open."""
+    if _emitter is not None and "compile" in event:
+        emit("compile", event=event, duration_s=float(duration))
 
-    Registered once per process — jax.monitoring has no unregister, so the
-    callbacks stay installed and gate on ``_emitter``."""
-    global _listeners_registered
-    if _listeners_registered:
-        return
-    _listeners_registered = True
-    try:
-        from jax import monitoring
-    except ImportError:
-        return
 
-    def _on_duration(event: str, duration: float, **kw) -> None:
-        if _emitter is not None and "compile" in event:
-            emit("compile", event=event, duration_s=float(duration))
-
-    def _on_event(event: str, **kw) -> None:
-        if _emitter is not None and ("cache" in event or "compile" in event):
-            emit("compile_cache", event=event)
-
-    monitoring.register_event_duration_secs_listener(_on_duration)
-    monitoring.register_event_listener(_on_event)
+def forward_cache(event: str) -> None:
+    """A ``jax.monitoring`` event: persistent-cache and compile events
+    become ``compile_cache`` records while a stream is open."""
+    if _emitter is not None and ("cache" in event or "compile" in event):
+        emit("compile_cache", event=event)
 
 
 # ---------------------------------------------------------------- helpers

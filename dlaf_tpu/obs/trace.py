@@ -20,10 +20,21 @@ Two distinct mechanisms, chosen by where the name must land:
     slices carry the phase name, plus an append to the module phase log
     when one is active (``start_phase_log``), which is how tests assert
     "this run entered >= N named phases" without hardware or a profiler.
+    Open phases form a per-thread name stack (:func:`current`).
+
+Program loads
+    Every program JAX compiles or loads from the persistent cache is charged
+    to the innermost open phase (``obs.stage`` enters one too) and marked on
+    the profiler timeline as a host span ``program_load/<phase>/<program>``
+    over the compile or load itself.  ``plan.core``'s ``jax.monitoring``
+    listeners call :func:`load_started` / :func:`load_finished`; the counts
+    are read with :func:`program_loads`.  Steady-state solves compile
+    nothing, so they never reach this code.
 
 Everything here is allocation-free on the off path: ``phase`` with no log
-active costs one TraceAnnotation enter/exit (nanoseconds, host-side only),
-and ``scope`` is plain ``jax.named_scope``.
+active costs one TraceAnnotation enter/exit (nanoseconds, host-side only)
+and one append/pop on the name stack, and ``scope`` is plain
+``jax.named_scope``.
 """
 from __future__ import annotations
 
@@ -37,6 +48,12 @@ from dlaf_tpu.obs import spans as _spans
 # Ordered log of phase names entered while a log is active (None = off).
 _phase_log: list | None = None
 _lock = threading.Lock()
+# Per thread: ``names``, the stack of open phases; ``loads``, the program
+# loads in progress as [TraceAnnotation, phase].
+_local = threading.local()
+#: phase -> {"compiled": n, "loaded": m}, process-cumulative.
+_loads: dict = {}
+NO_PHASE = "(no phase)"
 
 
 def scope(name: str):
@@ -65,6 +82,20 @@ def phase_log_active() -> bool:
     return _phase_log is not None
 
 
+def _stack(attr: str) -> list:
+    s = getattr(_local, attr, None)
+    if s is None:
+        s = []
+        setattr(_local, attr, s)
+    return s
+
+
+def current() -> str:
+    """The innermost phase open on this thread, or ``NO_PHASE``."""
+    names = getattr(_local, "names", None)
+    return names[-1] if names else NO_PHASE
+
+
 @contextlib.contextmanager
 def phase(name: str):
     """Host-level named phase around orchestration code (see module doc).
@@ -78,9 +109,45 @@ def phase(name: str):
         with _lock:
             if _phase_log is not None:
                 _phase_log.append(name)
-    if _spans.current_if_active() is not None:
-        with _spans.span(f"phase.{name}"), jax.profiler.TraceAnnotation(name):
-            yield
-    else:
-        with jax.profiler.TraceAnnotation(name):
-            yield
+    names = _stack("names")
+    names.append(name)
+    try:
+        if _spans.current_if_active() is not None:
+            with _spans.span(f"phase.{name}"), jax.profiler.TraceAnnotation(name):
+                yield
+        else:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+    finally:
+        names.pop()
+
+
+def load_started(program: str) -> None:
+    """A compile or persistent-cache load of ``program`` begins on this
+    thread: open its ``program_load/<phase>/<program>`` span."""
+    where = current()
+    ann = jax.profiler.TraceAnnotation(f"program_load/{where}/{program}")
+    ann.__enter__()
+    _stack("loads").append((ann, where))
+
+
+def load_finished(loaded: bool) -> None:
+    """The innermost load begun on this thread ended: close its span and
+    count it, as ``loaded`` from the persistent cache or else compiled."""
+    loads = _stack("loads")
+    if loads:
+        ann, where = loads.pop()
+        ann.__exit__(None, None, None)
+    else:  # begun before the listeners were registered
+        where = current()
+    with _lock:
+        rec = _loads.setdefault(where, {"compiled": 0, "loaded": 0})
+        rec["loaded" if loaded else "compiled"] += 1
+
+
+def program_loads() -> dict:
+    """Snapshot ``{phase: {"compiled": n, "loaded": m}}`` of every program
+    compiled or loaded since the process started, by the innermost phase
+    open at the time; subtract two snapshots to attribute a run."""
+    with _lock:
+        return {k: dict(v) for k, v in _loads.items()}

@@ -6,7 +6,8 @@ Three pieces:
   family and the serve layer resolve executables through
   :func:`cached`, whose key is built in one place
   (:func:`plan_key` = per-site static identity + :func:`trace_suffix`,
-  the full ambient trace-key set).  :func:`warmup` prefetches a bucket
+  the full ambient trace-key set); builders jit through :func:`jit`, which
+  names each program ``jit_<op>``.  :func:`warmup` prefetches a bucket
   ladder; with the persistent compilation cache configured
   (``tune.setup_compile_cache``) a respawned replica AOT-loads everything
   — zero backend compiles.
@@ -21,6 +22,7 @@ from dlaf_tpu.plan.core import (
     cached,
     compile_counts,
     evict,
+    jit,
     lookup,
     plan_key,
     reset,
@@ -34,6 +36,7 @@ __all__ = [
     "cached",
     "compile_counts",
     "evict",
+    "jit",
     "lookup",
     "plan_key",
     "reset",

@@ -25,9 +25,16 @@ backend compiles.  The jax.monitoring counters exposed by
 backend compiles when the persistent cache is on; ``pcache_hits`` = AOT
 loads), and every hit/miss/build/warmup flows through ``obs.metrics`` as
 ``plan`` events so cold-start cost is attributable from the JSONL stream.
+
+Names: every builder jits through :func:`jit`, so each program's module
+is ``jit_<op>`` — what profiler traces, compile logs and the program-load
+counter (``obs.program_loads``) show.  ``jax.jit`` of a ``partial`` or a
+``lambda`` would name it ``jit__unknown`` / ``jit__lambda_`` (lint rule
+DLAF005).
 """
 from __future__ import annotations
 
+import inspect
 import threading
 import time
 
@@ -47,30 +54,73 @@ _counters = {"hit": 0, "miss": 0, "build": 0, "evict": 0}
 #: cache dir is configured.
 _compile_events = {"backend_compiles": 0, "pcache_hits": 0, "pcache_misses": 0}
 _monitoring_registered = False
+#: JAX's event around one backend compile or persistent-cache load: a
+#: scalar (its start) then a duration, both with ``fun_name``
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# per thread: whether the compile in progress was a persistent-cache hit
+_pending = threading.local()
 
 
-def _register_monitoring() -> None:
-    """Count compile / persistent-cache events (idempotent; jax.monitoring
-    has no unregister, so the listeners stay installed for process life)."""
-    global _monitoring_registered
-    if _monitoring_registered:
-        return
-    _monitoring_registered = True
+def jit(op: str, fun, **jit_kwargs):
+    """``jax.jit(fun, **jit_kwargs)`` whose program is named ``jit_<op>``.
+
+    ``fun`` is wrapped in a function called ``op`` (its signature kept, so
+    static argument names still resolve); shardings, donation and every
+    other keyword pass through unchanged."""
+    import jax
+
+    def named(*args, **kwargs):
+        return fun(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = op
     try:
-        from jax import monitoring
-    except ImportError:  # pragma: no cover - jax is a hard dep elsewhere
-        return
+        named.__signature__ = inspect.signature(fun)
+    except (TypeError, ValueError):
+        pass
+    return jax.jit(named, **jit_kwargs)
+
+
+def register_monitoring() -> None:
+    """The process's one set of ``jax.monitoring`` listeners (idempotent;
+    jax.monitoring has no unregister, so they stay installed for process
+    life).  They count compile / persistent-cache events, charge each
+    compile or load to the open phase (``obs.trace.load_started`` /
+    ``load_finished``), and forward the events to the ``obs.metrics``
+    stream.  Only a compile or a load fires them."""
+    global _monitoring_registered
+    with _lock:
+        if _monitoring_registered:
+            return
+        _monitoring_registered = True
+    from jax import monitoring
+
+    from dlaf_tpu.obs import metrics as om
+    from dlaf_tpu.obs import trace as ot
+
+    def _on_start(event: str, value, fun_name: str = "?", **kw) -> None:
+        if event == _BACKEND_COMPILE:
+            _pending.hit = False
+            # "jit(op)" -> "jit_op", the module name the device trace shows
+            if fun_name.startswith("jit(") and fun_name.endswith(")"):
+                fun_name = f"jit_{fun_name[4:-1]}"
+            ot.load_started(fun_name)
 
     def _on_duration(event: str, duration: float, **kw) -> None:
-        if "backend_compile" in event:
+        if event == _BACKEND_COMPILE:
             _compile_events["backend_compiles"] += 1
+            ot.load_finished(loaded=getattr(_pending, "hit", False))
+            _pending.hit = False
+        om.forward_compile(event, duration)
 
     def _on_event(event: str, **kw) -> None:
         if event.endswith("/cache_hits"):
             _compile_events["pcache_hits"] += 1
+            _pending.hit = True
         elif event.endswith("/cache_misses"):
             _compile_events["pcache_misses"] += 1
+        om.forward_cache(event)
 
+    monitoring.register_scalar_listener(_on_start)
     monitoring.register_event_duration_secs_listener(_on_duration)
     monitoring.register_event_listener(_on_event)
 
@@ -78,7 +128,7 @@ def _register_monitoring() -> None:
 def compile_counts() -> dict:
     """Snapshot of the process-cumulative compile counters (see
     ``_compile_events``); subtract two snapshots to attribute a phase."""
-    _register_monitoring()
+    register_monitoring()
     return dict(_compile_events)
 
 
@@ -151,7 +201,7 @@ def cached(op: str, static_key: tuple, builder):
     ``obs.metrics`` (kind ``plan``) when a sink is active."""
     from dlaf_tpu.obs import metrics as om
 
-    _register_monitoring()
+    register_monitoring()
     key = plan_key(op, static_key)
     with _lock:
         fn = _entries.get(key)
@@ -247,7 +297,7 @@ def warmup(buckets=None, *, ops=("potrf", "posv", "eigh"), dtypes=("float32",),
     from dlaf_tpu.obs import metrics as om
     from dlaf_tpu.serve import batched, bucketing
 
-    _register_monitoring()
+    register_monitoring()
     if buckets is None:
         buckets = bucketing.bucket_table()
     records = []

@@ -261,7 +261,9 @@ class DeviceWatchdog:
         x = jax.device_put(
             np.ones((self._n, self._n), np.float32), self._device
         )
-        fn = jax.jit(lambda a: jnp.sum(a @ a))
+        from dlaf_tpu.plan import core as _plan
+
+        fn = _plan.jit("health_probe", lambda a: jnp.sum(a @ a))
         self._exec = fn.lower(x).compile()
         self._x = x
 

@@ -102,8 +102,9 @@ def _gather(mesh, *arrs):
     fn = _plan.cached(
         "serve_gather",
         tuple(int(d.id) for d in mesh.devices.flat),
-        lambda: jax.jit(
-            lambda *v: v, out_shardings=jax.sharding.NamedSharding(mesh, P())
+        lambda: _plan.jit(
+            "serve_gather", lambda *v: v,
+            out_shardings=jax.sharding.NamedSharding(mesh, P()),
         ),
     )
     rep = fn(*arrs)
@@ -223,7 +224,7 @@ def _build_chol_exec(grid: Grid, dist: Distribution, shard_batch: bool, variant:
     sm = jax.shard_map(
         jax.vmap(kern), mesh=mesh, in_specs=spec, out_specs=(spec, P(BATCH_AXIS)), check_vma=False
     )
-    return jax.jit(sm, donate_argnums=(0,))
+    return _plan.jit("serve", sm, donate_argnums=(0,))
 
 
 def _build_posv_batch_exec(grid: Grid, dist: Distribution, variant: str, uplo: str):
@@ -265,7 +266,7 @@ def _build_posv_batch_exec(grid: Grid, dist: Distribution, variant: str, uplo: s
         out_specs=(P(BATCH_AXIS), P(BATCH_AXIS)),
         check_vma=False,
     )
-    return jax.jit(sm, donate_argnums=(1,))
+    return _plan.jit("serve", sm, donate_argnums=(1,))
 
 
 def _build_posv_matrix_exec(grid: Grid, dist_a: Distribution, dist_b: Distribution,
@@ -298,7 +299,7 @@ def _build_posv_matrix_exec(grid: Grid, dist_a: Distribution, dist_b: Distributi
         out_specs=(spec, P(BATCH_AXIS)),
         check_vma=False,
     )
-    return jax.jit(sm, donate_argnums=(1,))
+    return _plan.jit("serve", sm, donate_argnums=(1,))
 
 
 def _build_eig_exec(grid: Grid):
@@ -318,7 +319,7 @@ def _build_eig_exec(grid: Grid):
         out_specs=(P(BATCH_AXIS), P(BATCH_AXIS), P(BATCH_AXIS)),
         check_vma=False,
     )
-    return jax.jit(sm, donate_argnums=(0,))
+    return _plan.jit("serve", sm, donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------- drivers

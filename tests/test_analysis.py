@@ -21,7 +21,7 @@ from dlaf_tpu.analysis import engine
 from dlaf_tpu.analysis.__main__ import repo_root
 from dlaf_tpu.analysis.engine import SourceFile
 from dlaf_tpu.analysis.project import Project
-from dlaf_tpu.analysis.rules import cache_keys, collectives, locks, purity
+from dlaf_tpu.analysis.rules import cache_keys, collectives, locks, program_names, purity
 
 TUNE_FIXTURE = """
 from dataclasses import dataclass
@@ -473,6 +473,59 @@ def test_dlaf004_blocking_and_completion_under_lock():
 def test_dlaf004_scope_is_serve_and_resilience_only():
     proj = _project({"dlaf_tpu/ops/fake.py": LOCK_FIXTURE}, with_tune=False)
     assert locks.check(proj) == []
+
+
+# ------------------------------------------------ DLAF005 program names
+
+
+NAMES_FIXTURE = """
+    import functools
+    from functools import partial
+
+    import jax
+    from jax import jit
+
+    def kernel(x, b):
+        return x * b
+
+    def build(b):
+        f1 = jax.jit(partial(kernel, b=b), donate_argnums=(0,))
+        f2 = jit(lambda x: x + 1)
+        f3 = jax.jit(functools.partial(kernel, b=2))
+        return f1, f2, f3
+
+    @partial(jax.jit, static_argnums=(1,))
+    def named(x, b):
+        return x * b
+
+    def build_named(b, plan):
+        def run(x):
+            return kernel(x, b)
+
+        return jax.jit(run), plan.jit("kernel", partial(kernel, b=b))
+"""
+
+
+def test_dlaf005_unnamed_programs_flagged():
+    proj = _project({"dlaf_tpu/algorithms/fake.py": NAMES_FIXTURE}, with_tune=False)
+    got = sorted((f.line, f.symbol, f.message.split(" — ")[0])
+                 for f in program_names.check(proj))
+    assert got == [
+        (12, "build", "jax.jit of a partial compiles an unnamed program"),
+        (13, "build", "jax.jit of a lambda compiles an unnamed program"),
+        (14, "build", "jax.jit of a partial compiles an unnamed program"),
+    ]
+
+
+def test_dlaf005_named_programs_and_code_outside_the_library_clean():
+    """A jitted ``def``, ``@partial(jax.jit, ...)`` and ``plan.jit`` keep a
+    name; tests and scripts may jit what they like."""
+    named = NAMES_FIXTURE.split("    def build(b):")[0] + NAMES_FIXTURE.split(
+        "        return f1, f2, f3\n")[1]
+    proj = _project({"dlaf_tpu/algorithms/fake.py": named}, with_tune=False)
+    assert program_names.check(proj) == []
+    proj = _project({"scripts/fake.py": NAMES_FIXTURE}, with_tune=False)
+    assert program_names.check(proj) == []
 
 
 # -------------------------------------------- suppressions, baseline, CLI
