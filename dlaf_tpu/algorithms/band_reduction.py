@@ -16,8 +16,11 @@ block (the R diagonal lands exactly on distance b2), then chase the bulge:
 each chase step QRs the b1 x b1 fill block [S[0]+b1, S[-1]+b1] x S (R
 diagonal at distance b1) and applies Q two-sided inside a sliding dense
 3*b1 window of the band.  Transient bandwidth stays < 2*b1, so the band
-lives in compact [2*b1, n_pad] storage; every step densifies one window,
-updates it, and scatters it back.
+lives in compact [2*b1, n_pad] storage.  Every step skews one [2*b1,
+3*b1] band slice into a dense window, updates it, and skews its lower band
+back; each skew is a pad and a reshape (no index arrays, so no gather on
+the device), and the window's corners beyond distance 2*b1 are exact
+zeros.
 
 The per-step b1 x b1 Q blocks — O(n^2 b1/b2) elements total — are staged
 to HOST in fixed-size sweep chunks (the device only ever holds one
@@ -86,6 +89,35 @@ def _sweep_chunks(n: int, b1: int, b2: int):
     return out
 
 
+def _densify(abw):
+    """Dense window M[i, j] = A[w0+i, w0+j] of a compact band slice
+    ``abw`` [2*b1, 3*b1] (abw[d, j] = A[w0+j+d, w0+j]).
+
+    Column j of the band moves down by j rows, a fixed skew done as a pad
+    and a reshape: lt[j, i] = abw[i-j, j].  The lower triangle is lt.T,
+    the upper conj(lt); beyond distance 2*b1 both are exact zeros."""
+    import jax.numpy as jnp
+
+    S, W = abw.shape
+    lt = jnp.pad(abw.T, ((0, 0), (0, W + 1 - S))).reshape(-1)[: W * W]
+    lt = lt.reshape(W, W)
+    dd = jnp.arange(W)[:, None] - jnp.arange(W)[None, :]
+    lower = (dd >= 0) & (dd < S)
+    return jnp.where(lower, lt.T, jnp.where(dd < 0, jnp.conj(lt), 0))
+
+
+def _scatter(abw, M):
+    """Write the lower band of the window ``M`` back into ``abw``: the
+    inverse skew, abw[d, j] = M[j+d, j] where j+d lies inside the window;
+    entries past its edge keep ``abw``'s."""
+    import jax.numpy as jnp
+
+    S, W = abw.shape
+    sk = jnp.pad(M.T.reshape(-1), (0, W)).reshape(W, W + 1)[:, :S].T
+    s_valid = jnp.arange(S)[:, None] + jnp.arange(W)[None, :] < W
+    return jnp.where(s_valid, sk, abw)
+
+
 def _sbr_chunk_kernel(
     ab, qstack, s_base, *, b1: int, b2: int, CH: int, K: int, want_q: bool
 ):
@@ -100,29 +132,10 @@ def _sbr_chunk_kernel(
 
     W = 3 * b1
     S = 2 * b1
-    ii = jnp.arange(W)[:, None]
-    jj = jnp.arange(W)[None, :]
-    dd = ii - jj
-    lower = (dd >= 0) & (dd < S)
-    dl = jnp.clip(dd, 0, S - 1)
-    du = jnp.clip(-dd, 0, S - 1)
-    sd = jnp.arange(S)[:, None]
-    sj = jnp.arange(W)[None, :]
-    s_valid = sd + sj < W
-    s_row = jnp.clip(sd + sj, 0, W - 1)
-
-    def densify(abw):
-        # M[i, j] = A[w0+i, w0+j]: lower from abw[i-j, j], upper by symmetry
-        low = abw[dl, jj]
-        up = jnp.conj(abw[du, jnp.broadcast_to(ii, (W, W))])
-        return jnp.where(lower, low, jnp.where(dd < 0, up, 0))
-
-    def scatter(abw, M):
-        return jnp.where(s_valid, M[s_row, sj], abw)
 
     def step(ab, w0, row_off: int, col_w: int):
         abw = lax.dynamic_slice(ab, (jnp.asarray(0, w0.dtype), w0), (S, W))
-        M = densify(abw)
+        M = _densify(abw)
         B = M[row_off : row_off + b1, 0:col_w]
         Q, _ = jnp.linalg.qr(B, mode="complete")
         # zero block => no-op: QR may return any orthogonal Q, but mixing
@@ -131,7 +144,7 @@ def _sbr_chunk_kernel(
         rows = slice(row_off, row_off + b1)
         M = M.at[rows, :].set(Q.conj().T @ M[rows, :])
         M = M.at[:, rows].set(M[:, rows] @ Q)
-        abw = scatter(abw, M)
+        abw = _scatter(abw, M)
         ab = lax.dynamic_update_slice(ab, abw, (jnp.asarray(0, w0.dtype), w0))
         return ab, Q
 

@@ -9,7 +9,9 @@ import dlaf_tpu.testing as tu
 from dlaf_tpu.algorithms.band_reduction import (
     SbrTransforms,
     _chase_bound,
+    _densify,
     _n_sweeps,
+    _scatter,
     sbr_back_transform,
     sbr_reduce,
 )
@@ -47,9 +49,67 @@ def _from_compact(ab, n, b):
     return a
 
 
+def _oracle_densify(abw):
+    """The window by index arrays: lower from abw[i-j, j], upper from
+    conj(abw[j-i, i]), indices clipped into the band."""
+    S, W = abw.shape
+    ii = np.arange(W)[:, None]
+    jj = np.arange(W)[None, :]
+    dd = ii - jj
+    low = abw[np.clip(dd, 0, S - 1), jj]
+    up = np.conj(abw[np.clip(-dd, 0, S - 1), np.broadcast_to(ii, (W, W))])
+    return np.where((dd >= 0) & (dd < S), low, np.where(dd < 0, up, 0))
+
+
+def _oracle_scatter(abw, m):
+    S, W = abw.shape
+    sd = np.arange(S)[:, None]
+    sj = np.arange(W)[None, :]
+    return np.where(sd + sj < W, m[np.clip(sd + sj, 0, W - 1), sj], abw)
+
+
+def _random(shape, dtype, rng):
+    x = rng.standard_normal(shape)
+    if np.dtype(dtype).kind == "c":
+        x = x + 1j * rng.standard_normal(shape)
+    return x.astype(dtype)
+
+
+_SKEW_DTYPES = [np.float32, np.float64, np.complex64, np.complex128]
+
+
+@pytest.mark.parametrize("b1", [4, 16, 128])
+@pytest.mark.parametrize("dtype", _SKEW_DTYPES, ids=str)
+def test_densify_skew(b1, dtype):
+    """The pad-and-reshape window equals the index-array one inside the
+    band |i-j| < 2*b1 and is exactly zero beyond it."""
+    import jax.numpy as jnp
+
+    abw = _random((2 * b1, 3 * b1), dtype, np.random.default_rng(b1))
+    got = np.asarray(_densify(jnp.asarray(abw)))
+    W = 3 * b1
+    band = np.abs(np.subtract.outer(np.arange(W), np.arange(W))) < 2 * b1
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(got[band], _oracle_densify(abw)[band])
+    assert not got[~band].any()
+
+
+@pytest.mark.parametrize("b1", [4, 16, 128])
+@pytest.mark.parametrize("dtype", _SKEW_DTYPES, ids=str)
+def test_scatter_skew(b1, dtype):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(b1 + 1)
+    abw = _random((2 * b1, 3 * b1), dtype, rng)
+    m = _random((3 * b1, 3 * b1), dtype, rng)
+    got = np.asarray(_scatter(jnp.asarray(abw), jnp.asarray(m)))
+    np.testing.assert_array_equal(got, _oracle_scatter(abw, m))
+
+
 @pytest.mark.parametrize(
     "n,b1,b2",
-    [(64, 8, 2), (64, 8, 4), (96, 16, 4), (61, 8, 4), (40, 16, 4), (33, 4, 2)],
+    [(64, 8, 2), (64, 8, 4), (96, 16, 4), (61, 8, 4), (40, 16, 4), (33, 4, 2),
+     (512, 128, 32)],
 )
 @pytest.mark.parametrize("dtype", [np.float64, np.complex128], ids=str)
 def test_sbr_reduce(n, b1, b2, dtype):

@@ -9,6 +9,7 @@ only one process may load libtpu, and pytest-xdist workers import every
 test file.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +113,27 @@ def test_secular_bisect_compiles(one_chip):
     tab, vec = _shape((k, s), one_chip), _shape((k,), one_chip)
     jax.jit(lambda *a: pallas_secular.secular_bisect(*a, 40)).lower(
         tab, tab, vec, vec, vec, vec).compile()
+
+
+def test_sbr_chunk_has_no_gather(one_chip):
+    """One SBR chunk at the N=4096 HEEV shape (b1=128, b2=32, 16 sweeps of
+    32 chase steps), as ``sbr_reduce`` builds it: the window moves are
+    layout ops, with no element gather on the chip."""
+    from functools import partial
+
+    from dlaf_tpu.algorithms.band_reduction import _sbr_chunk_kernel
+    from dlaf_tpu.tune import matmul_precision
+
+    b1, b2, CH, K, n_pad = 128, 32, 16, 32, 4640
+    f = jax.jit(partial(_sbr_chunk_kernel, b1=b1, b2=b2, CH=CH, K=K, want_q=True),
+                donate_argnums=(0, 1))
+    with matmul_precision("float32"), _x64(False):
+        compiled = f.lower(_shape((2 * b1, n_pad), one_chip),
+                           _shape((CH, K + 1, b1, b1), one_chip),
+                           _shape((), one_chip, jnp.int32)).compile()
+    # match instructions: the text's stack-frame table holds this test's name
+    ops = re.findall(r" (gather|scatter)\(", compiled.as_text())
+    assert not ops, f"{len(ops)} gather/scatter ops in jit_sbr_chunk"
 
 
 def test_ring_exchange_compiles_on_2x2(mesh_2x2):
