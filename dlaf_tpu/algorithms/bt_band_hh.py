@@ -27,6 +27,7 @@ layout the loop is communication-free across devices.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import numpy as np
 
@@ -49,55 +50,90 @@ def _resolve_group_size(group_size):
     return group_size
 
 
-def hh_schedule(n: int, b: int, g: int):
-    """Group schedule in application order.
+class HHSchedule(NamedTuple):
+    """The grouped-WY schedule of one (n, b, g), groups in application order.
 
-    Returns (groups, w) where each group is (base_shifted, [(col, slot), ...])
-    with ``col`` the reflector's column inside the group's V (head offset
-    within the window is ``col + delta``) and ``slot`` its storage index in
-    the [R, b] reflector array; w = b + g - 1 is the window height.
-    """
-    if b <= 1 or n <= 2:
-        return [], 0
-    nsweeps = n - 2  # sweeps s = 0 .. n-3
-    counts = [(n - 3 - s) // b + 1 for s in range(nsweeps)]
-    offs = np.concatenate([[0], np.cumsum(counts)])
+    Group (J, m) holds chase level ``m`` of sweeps ``J*g .. J*g+g-1``:
+    column ``c`` is reflector (J*g+c, m), kept in row ``rows[gi, c]`` of
+    the [R, b] reflector array (R where the reflector does not exist), and
+    heads window row ``delta[gi] + c``."""
+
+    w: int  # window height b + g - 1
+    rows: np.ndarray  # [G, g] int32 reflector slot of each column
+    offs: np.ndarray  # [G] int32 first E row of each group's window
+    delta: np.ndarray  # [G] int32 bottom-edge clamp: head row - window row
+
+
+def hh_schedule(n: int, b: int, g: int) -> HHSchedule:
+    """Group schedule in application order: sweep blocks J descending,
+    chase levels m ascending.  Sweep s (0 .. n-3) owns one reflector per
+    chase level, (n-3-s)//b + 1 of them, in consecutive slots.  Needs
+    b > 1 and n > 2."""
+    nsweeps = n - 2
     w = b + g - 1
     n_pad = max(n, w)
-    groups = []
-    first_block = ((nsweeps - 1) // g) * g
-    for j0 in range(first_block, -1, -g):
-        j1 = min(j0 + g, nsweeps)
-        mmax = (n - 3 - j0) // b
-        for m in range(mmax + 1):
-            base = 1 + j0 + m * b
-            base_s = min(base, n_pad - w)
-            delta = base - base_s
-            cols = []
-            for s in range(j0, j1):
-                if 1 + s + m * b <= n - 2:
-                    cols.append((delta + (s - j0), int(offs[s]) + m))
-            if cols:
-                groups.append((base_s, cols))
-    return groups, w
+    counts = (n - 3 - np.arange(nsweeps)) // b + 1
+    slot = np.concatenate([[0], np.cumsum(counts)])
+    j0 = np.arange(0, nsweeps, g)[::-1]
+    jj = np.repeat(j0, counts[j0])
+    m = np.arange(jj.size) - np.repeat(np.cumsum(counts[j0]) - counts[j0], counts[j0])
+    s = jj[:, None] + np.arange(g)
+    sc = np.minimum(s, nsweeps - 1)
+    rows = np.where((s < nsweeps) & (m[:, None] < counts[sc]), slot[sc] + m[:, None], slot[-1])
+    base = 1 + jj + m * b
+    offs = np.minimum(base, n_pad - w)
+    return HHSchedule(
+        w, rows.astype(np.int32), offs.astype(np.int32), (base - offs).astype(np.int32)
+    )
 
 
-def _build_factors(v_refl, taus, groups, w, g, b, dtype):
-    """Host assembly of the padded per-group V windows and taus."""
-    G = len(groups)
-    V_all = np.zeros((G, w, g), dtype)
-    tau_all = np.ones((G, g), dtype)  # pad: tau=1 with v=0 => identity factor
-    offs = np.zeros(G, np.int32)
-    for gi, (base_s, cols) in enumerate(groups):
-        offs[gi] = base_s
-        for ci, (row_off, slot) in enumerate(cols):
-            t = taus[slot]
-            if t == 0:
-                continue  # identity reflector: leave v=0, tau=1
-            L = min(b, w - row_off)
-            V_all[gi, row_off : row_off + L, ci] = v_refl[slot, :L]
-            tau_all[gi, ci] = t
-    return V_all, tau_all, offs
+def _form_factors(v_flat, taus, *, sched: HHSchedule, b: int, g: int):
+    """The padded per-group V windows [G, w, g] and taus [G, g] from the
+    compact reflectors (``v_flat`` the [R, b] array flattened), on device.
+
+    One gather of whole reflector rows puts each group's [g, b] block
+    together; the block is skewed into its window (column c moves down c
+    rows: a pad and a reshape), and the groups clamped at the bottom edge
+    move down ``delta`` more rows (a shift by each set bit of delta).  An
+    identity reflector (tau == 0) and a missing one read v = 0, tau = 1."""
+    import jax.numpy as jnp
+
+    w = sched.w
+    G = sched.offs.size
+    # tau rides along as column b; row R is the missing reflector
+    vt = jnp.concatenate([v_flat.reshape(-1, b), taus[:, None]], axis=1)
+    vt = jnp.pad(vt, ((0, 1), (0, 0)))
+    x = vt.at[jnp.asarray(sched.rows)].get(mode="promise_in_bounds")
+    t = x[..., b]
+    ident = t == 0
+    v = jnp.where(ident[..., None], 0, x[..., :b])
+    y = jnp.pad(v, ((0, 0), (0, 0), (0, g))).reshape(G, g * (w + 1))[:, : g * w]
+    y = y.reshape(G, g, w)
+    for k in range(int(sched.delta.max()).bit_length()):
+        moved = jnp.pad(y, ((0, 0), (0, 0), (1 << k, 0)))[..., :w]
+        y = jnp.where((sched.delta >> k & 1).astype(bool)[:, None, None], moved, y)
+    return y.transpose(0, 2, 1), jnp.where(ident, 1, t), jnp.asarray(sched.offs)
+
+
+def _factors(v_refl, taus, n: int, b: int, g: int, dtype):
+    """(w, G, (V_all, tau_all, offs)): the group schedule of (n, b, g) and
+    its window-forming program, both built once per shape and dtype; per
+    call only the chase's reflectors and taus go to the device."""
+    import jax.numpy as jnp
+
+    from dlaf_tpu.plan import core as _plan
+
+    dt = np.dtype(dtype)
+
+    def build():
+        sched = hh_schedule(n, b, g)
+        return sched, _plan.jit(
+            "bt_band_factors", partial(_form_factors, sched=sched, b=b, g=g)
+        )
+
+    sched, form = _plan.cached("bt_band_factors", (n, b, g, dt), build)
+    v = jnp.asarray(np.asarray(v_refl, dt).reshape(-1))
+    return sched.w, sched.offs.size, form(v, jnp.asarray(np.asarray(taus, dt)))
 
 
 def _wy_group_loop(e_pad, V_all, tau_all, offs, w, g, G, k):
@@ -199,19 +235,15 @@ def bt_band_to_tridiagonal_hh_dist(
     with phase("bt_band/factors"):
         if has_refl:
             g = max(1, min(group_size, band, n - 2))
-            groups, w = hh_schedule(n, band, g)
-            V_all, tau_all, offs = _build_factors(v_refl, taus, groups, w, g, band, dt)
-            G = len(groups)
+            w, G, wy = _factors(v_refl, taus, n, band, g, dt)
         else:
             g, w, G = 1, 1, 0
-            V_all = np.zeros((0, 1, 1), dt)
-            tau_all = np.ones((0, 1), dt)
-            offs = np.zeros(0, np.int32)
+            wy = (np.zeros((0, 1, 1), dt), np.ones((0, 1), dt), np.zeros(0, np.int32))
         n_pad = max(n, w)
         ph = np.ones(n_pad, dt)
         if dt.kind == "c":
             ph[:n] = phases.astype(dt)
-        factors = tuple(jnp.asarray(a) for a in (V_all, tau_all, offs, ph))
+        factors = tuple(jnp.asarray(a) for a in (*wy, ph))
     Ptot = grid.grid_size.count()
     kloc = -(-k // Ptot)
     kpad = kloc * Ptot
@@ -284,24 +316,23 @@ def bt_band_to_tridiagonal_hh(
     n, k = e_host.shape
     if dt.kind == "c":
         e_host = phases[:, None] * e_host
-    if v_refl.shape[0] == 0 or n == 0 or k == 0:
+    if v_refl.shape[0] == 0 or n <= 2 or k == 0 or band <= 1:
         return DistributedMatrix.from_global(grid, e_host, block_size)
     from dlaf_tpu.tune import get_tune_parameters, matmul_precision
 
     group_size = _resolve_group_size(group_size)
     g = max(1, min(group_size, band, n - 2))
-    groups, w = hh_schedule(n, band, g)
-    V_all, tau_all, offs = _build_factors(v_refl, taus, groups, w, g, band, dt)
+    w, G, wy = _factors(v_refl, taus, n, band, g, dt)
     n_pad = max(n, w)
     e_pad = e_host if n_pad == n else np.pad(e_host, ((0, n_pad - n), (0, 0)))
 
     dist = Distribution(Size2D(n, k), Size2D(*block_size), grid.grid_size, Index2D(0, 0))
     prec = get_tune_parameters().eigensolver_matmul_precision
     fn = _apply_fn(
-        n_pad, k, w, g, len(groups), dt,
+        n_pad, k, w, g, G, dt,
         dist_key=(grid.cache_key, dist), dist=dist, sharding=grid.stacked_sharding(),
         prec=prec,
     )
     with matmul_precision(prec):
-        data = fn(jnp.asarray(e_pad), jnp.asarray(V_all), jnp.asarray(tau_all), jnp.asarray(offs))
+        data = fn(jnp.asarray(e_pad), *wy)
     return DistributedMatrix(dist, grid, data)

@@ -136,6 +136,55 @@ def test_sbr_chunk_has_no_gather(one_chip):
     assert not ops, f"{len(ops)} gather/scatter ops in jit_sbr_chunk"
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.complex64])
+def test_bt_band_factors_gather_whole_rows(one_chip, dtype):
+    """The WY window program at the N=4096 HEEV shape (b=32 after SBR,
+    g=32): a gather there moves whole reflector rows (b values and the
+    tau beside them), never single elements, and nothing scatters."""
+    from functools import partial
+
+    from dlaf_tpu.algorithms.bt_band_hh import _form_factors, hh_schedule
+
+    n, b, g = 4096, 32, 32
+    sched = hh_schedule(n, b, g)
+    R = int(sched.rows.max())  # the missing-reflector row
+    f = jax.jit(partial(_form_factors, sched=sched, b=b, g=g))
+    with _x64(False):
+        text = f.lower(_shape((R * b,), one_chip, dtype),
+                       _shape((R,), one_chip, dtype)).compile().as_text()
+    assert not re.findall(r" scatter\(", text)
+    sizes = re.findall(r" gather\(.*slice_sizes=\{([0-9,]+)\}", text)
+    assert len(sizes) == len(re.findall(r" gather\(", text))
+    assert set(sizes) <= {f"1,{b + 1}"}, sizes
+
+
+def test_bt_band_factors_built_once(monkeypatch):
+    """Two back-transforms of one shape build the group schedule and
+    compile the window program once."""
+    from dlaf_tpu.algorithms import bt_band_hh
+    from dlaf_tpu.common.index import Size2D
+    from dlaf_tpu.comm.grid import Grid
+    from dlaf_tpu.matrix.matrix import DistributedMatrix
+    from dlaf_tpu.plan import core as plan
+
+    n, b, g, dt = 31, 3, 2, np.float32
+    plan.evict(plan.plan_key("bt_band_factors", (n, b, g, np.dtype(dt))))
+    built = []
+    schedule = bt_band_hh.hh_schedule
+    monkeypatch.setattr(bt_band_hh, "hh_schedule", lambda *a: built.append(a) or schedule(*a))
+    R = sum((n - 3 - s) // b + 1 for s in range(n - 2))
+    rng = np.random.default_rng(0)
+    grid = Grid.create(Size2D(1, 1), jax.devices()[:1])
+    hh = (None, None, np.ones(n, dt), rng.standard_normal((R, b)).astype(dt),
+          rng.uniform(1, 2, R).astype(dt), b)
+    for _ in range(2):
+        mat = DistributedMatrix.from_global(grid, rng.standard_normal((n, 5)).astype(dt), (8, 8))
+        bt_band_hh.bt_band_to_tridiagonal_hh_dist(hh, mat, group_size=g)
+    assert built == [(n, b, g)]
+    _, form = plan.lookup(plan.plan_key("bt_band_factors", (n, b, g, np.dtype(dt))))
+    assert form._cache_size() == 1
+
+
 def test_ring_exchange_compiles_on_2x2(mesh_2x2):
     """The remote-DMA panel ring along 'c' of the 2x2 mesh (16 tiles of
     256x256 f32)."""
