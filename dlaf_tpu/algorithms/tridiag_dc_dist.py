@@ -25,7 +25,9 @@ one size) is a constant number of jitted SPMD calls:
     materialized G.
   * The secular equation is solved by vectorized bisection in the anchored
     (nearest-pole) representation, root-sharded over the whole device mesh
-    and all_gathered (replaces the reference's nworkers laed4 tasks).
+    and all_gathered (replaces the reference's nworkers laed4 tasks).  Each
+    root's pole window is its merge block's row of the [B, S] poles, moved
+    as a whole block row, never gathered one element at a time.
   * The rank-1 eigenvector basis U is elementwise in O(s) replicated
     vectors (zhat, poles, anchors, offsets, column norms) via the Loewner
     z-recomputation, evaluated in log-space (interlacing makes every
@@ -155,6 +157,13 @@ def _leaf_kernel(qL, *, g, s0, nleaf, nloc, dt):
 # --------------------------------------------------------------------------
 
 
+def _block_rows(v, bq, S):
+    """The [RPD, S] window whose row i is merge block ``bq[i]`` of ``v``:
+    ``v[bq[:, None] * S + arange(S)]``, as one gather of whole block rows
+    (never S single elements per root)."""
+    return v.reshape(-1, S)[bq]
+
+
 def _params_kernel(x, lam_prev, beta, *, g, S, B, n_pad, RPD, iters, dt):
     x = coll.local(x)
     myr, myc = coll.my_rank()
@@ -246,9 +255,10 @@ def _params_kernel(x, lam_prev, beta, *, g, S, B, n_pad, RPD, iters, dt):
     z2_flat = jnp.where(keep, zpost * zpost, 0.0).reshape(-1)
     pos = jnp.clip(flat * RPD + jnp.arange(RPD), 0, n_pad - 1)
     bq = pos // S
-    win = bq[:, None] * S + jnp.arange(S)[None, :]  # [RPD, S]
-    dw = ds_flat[win]
-    z2w = z2_flat[win]
+    rows = partial(_block_rows, bq=bq, S=S)
+    j_blk = jnp.arange(S)[None, :]  # window column = index within the block
+    dw = rows(ds_flat)  # [RPD, S]
+    z2w = rows(z2_flat)
     rho_q = rho[bq]
     # next active pole / per-block upper bound
     maskedd = jnp.where(keep, ds, jnp.inf)
@@ -327,7 +337,7 @@ def _params_kernel(x, lam_prev, beta, *, g, S, B, n_pad, RPD, iters, dt):
     lo_g = jnp.where(use_r, -gap_q, jnp.zeros_like(gap_q))
     hi_g = jnp.where(use_r, jnp.zeros_like(gap_q), gap_q)
     ag_r = dw - anchor_q[:, None]
-    own_sel = (win == a_idx[:, None])
+    own_sel = j_blk == (a_idx - bq * S)[:, None]
 
     # only roots at/below the bisection resolution floor need (and safely
     # admit) the fixed-point; larger offsets already have the relative
@@ -356,12 +366,12 @@ def _params_kernel(x, lam_prev, beta, *, g, S, B, n_pad, RPD, iters, dt):
     lam = gather_flat(lam_q)
 
     # --- zhat via Loewner formula in log space (shard over j) --------------
-    aw = anchor[win]
-    ow = off[win]
-    kw = keep_flat[win]
+    aw = rows(anchor)
+    ow = rows(off)
+    kw = rows(keep_flat)
     numw = (aw - d_q[:, None]) + ow
     denw = dw - d_q[:, None]
-    act = kw & kq[:, None] & (win != pos[:, None])
+    act = kw & kq[:, None] & (j_blk != (pos - bq * S)[:, None])
     logratio = jnp.where(
         act,
         jnp.log(jnp.maximum(jnp.abs(numw), tiny))
@@ -380,7 +390,7 @@ def _params_kernel(x, lam_prev, beta, *, g, S, B, n_pad, RPD, iters, dt):
     zhat = gather_flat(zhat_q)
 
     # --- column norms of U (shard over columns t) ---------------------------
-    zh2w = (zhat * zhat)[win]
+    zh2w = rows(zhat * zhat)
     numw2 = (anchor[pos][:, None] - dw) + off[pos][:, None]
     safe2 = jnp.where(numw2 == 0, tiny, numw2)
     nsum = jnp.sum(jnp.where(kw, zh2w / (safe2 * safe2), 0.0), axis=1)

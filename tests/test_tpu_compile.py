@@ -136,6 +136,45 @@ def test_sbr_chunk_has_no_gather(one_chip):
     assert not ops, f"{len(ops)} gather/scatter ops in jit_sbr_chunk"
 
 
+@pytest.mark.parametrize("shape,S", [((1, 1), 1024), ((1, 1), 2048), ((1, 1), 4096),
+                                     ((2, 2), 1024)])
+def test_dc_params_no_element_gather(topo, shape, S):
+    """The D&C secular-parameter program at the N=4096 HEEV shape (nb=256,
+    leaf 512): the three merge levels on one chip, and the first on the
+    2x2 mesh.  Each root's [RPD, S] pole windows come from whole block
+    rows; no gather fetches them one element at a time."""
+    from functools import partial
+
+    import dlaf_tpu as dt
+    from dlaf_tpu.algorithms import _spmd
+    from dlaf_tpu.algorithms.tridiag_dc_dist import _params_kernel
+    from dlaf_tpu.matrix.matrix import DistributedMatrix
+
+    n_pad, nb = 4096, 256
+    B, P_ = n_pad // S, shape[0] * shape[1]
+    RPD = -(-n_pad // P_)
+    devs = np.array(topo.devices[:P_]).reshape(shape)
+    grid = dt.Grid(Mesh(devs, ("r", "c")))
+    dist = dt.Distribution(dt.Size2D(n_pad, n_pad), dt.Size2D(nb, nb), grid.grid_size,
+                           dt.Index2D(0, 0))
+    rep, stacked = P(), P("r", "c")
+    kern = partial(_params_kernel, g=_spmd.Geometry.of(dist), S=S, B=B, n_pad=n_pad,
+                   RPD=RPD, iters=42, dt=jnp.dtype(jnp.float32))
+    f = jax.jit(jax.shard_map(kern, mesh=grid.mesh, in_specs=(stacked, rep, rep),
+                              out_specs=(rep,) * 16, check_vma=False))
+    with _x64(False):
+        text = f.lower(
+            _shape(DistributedMatrix.stacked_shape(dist), grid.stacked_sharding()),
+            _shape((n_pad,), NamedSharding(grid.mesh, rep)),
+            _shape((B,), NamedSharding(grid.mesh, rep))).compile().as_text()
+    gathers = re.findall(r" = \w+\[([0-9,]*)\]\S* gather\(.*slice_sizes=\{([0-9,]+)\}", text)
+    assert len(gathers) == len(re.findall(r" gather\(", text))
+    # a pole window is an [RPD, S] output: only whole-row gathers may make it
+    windows = [(out, sl) for out, sl in gathers if out.count(",") == 1
+               and int(out.split(",")[0]) >= RPD and int(out.split(",")[1]) >= S]
+    assert {sl for _, sl in windows} <= {f"1,{S}"}, windows
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.complex64])
 def test_bt_band_factors_gather_whole_rows(one_chip, dtype):
     """The WY window program at the N=4096 HEEV shape (b=32 after SBR,

@@ -5,11 +5,15 @@ Mirrors the reference's tridiag_solver distributed tests
 the clustered-spectrum stress the reference exercises through its
 deflation-path unit tests (test_tridiag_solver_merge.cpp).
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 import scipy.linalg as sla
 
-from dlaf_tpu.algorithms.tridiag_dc_dist import tridiag_dc_distributed
+from dlaf_tpu.algorithms.tridiag_dc_dist import _block_rows, tridiag_dc_distributed
+from dlaf_tpu.comm.grid import Grid
+from dlaf_tpu.common.index import Size2D
 from dlaf_tpu.tune import get_tune_parameters
 
 
@@ -59,6 +63,44 @@ def test_dc_dist_grids(comm_grids, leaf_size, n, nb):
     d, e = _random_tridiag(rng, n)
     for grid in comm_grids:
         _check(grid, d, e, nb, np.float64)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64, np.bool_])
+@pytest.mark.parametrize(
+    "n_pad,S,P",
+    [
+        (64, 16, 1),  # one device: RPD == n_pad
+        (64, 16, 2),  # RPD a multiple of S
+        (64, 32, 3),  # RPD < S, roots straddle two blocks; clipped tail
+        (64, 16, 6),  # 2x3: RPD < S, clipped tail
+        (96, 32, 4),  # RPD < S, straddling, P divides n_pad
+    ],
+)
+def test_block_rows_matches_index_window(n_pad, S, P, dtype):
+    """Each device's pole window from whole block rows equals the window
+    indexed one element at a time, for every device of the flat mesh."""
+    rng = np.random.default_rng(n_pad + S + P)
+    v = jnp.asarray(rng.standard_normal(n_pad) > 0 if dtype is np.bool_
+                    else rng.standard_normal(n_pad).astype(dtype))
+    RPD = -(-n_pad // P)
+    for flat in range(P):
+        pos = jnp.clip(flat * RPD + jnp.arange(RPD), 0, n_pad - 1)
+        bq = pos // S
+        want = v[bq[:, None] * S + jnp.arange(S)[None, :]]
+        got = _block_rows(v, bq, S)
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("leaf_size", [32], indirect=True)
+@pytest.mark.parametrize("shape", [(2, 3), (3, 1)])
+def test_dc_dist_odd_grids(leaf_size, shape):
+    """Grids whose device count does not divide n_pad: roots straddle
+    merge blocks and the last device's roots are clipped."""
+    grid = Grid.create(Size2D(*shape), jax.devices()[: shape[0] * shape[1]])
+    rng = np.random.default_rng(8)
+    d, e = _random_tridiag(rng, 200)
+    _check(grid, d, e, 16, np.float64)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64, np.complex128])
